@@ -122,11 +122,11 @@ def policy_scores(policy_id, active, laxity, release, utility, mandatory,
     edfm = edfm_key(laxity, release, mandatory)
     rr = rr_key(release, task_rank)
 
-    scores = jnp.select(
-        [policy_id == 0, policy_id == 1, policy_id == 2],
-        [zyg, edf, edfm],
-        rr,
-    )
+    # chained where, not jnp.select: select lowers through an argmax over
+    # the condition stack, which Mosaic refuses for bool operands
+    scores = jnp.where(policy_id == 0, zyg,
+                       jnp.where(policy_id == 1, edf,
+                                 jnp.where(policy_id == 2, edfm, rr)))
     scores = jnp.where(active.astype(bool), scores, NEG)
     # zygarde idles when even the best score is <= 0 (energy-gated optional
     # work); the deadline-keyed policies only idle on an empty queue.

@@ -96,14 +96,42 @@ def _oh_eq(idx, n: int):
     return iota == idx[..., None]
 
 
-def _take(table, idx):
-    """``table[..., idx]`` over the trailing axis.
+def _col(mask):
+    """``mask[..., None]`` for a bool ``mask``, expanded as int32: Mosaic
+    refuses shape casts of bool vectors."""
+    return mask.astype(jnp.int32)[..., None] != 0
 
-    ``table``: ``(..., N)``; ``idx``: int ``(..., Q)`` with the same leading
-    axes -> ``(..., Q)`` in ``table.dtype``.  Exact: one hot lane (one-hot
-    lowering) / clamped gather (XLA lowering); call sites mask any slot
-    whose index can be out of range.
+
+def select_bool(cond, a, b):
+    """``jnp.where(cond, a, b)`` for bool ``a``/``b`` as mask algebra:
+    Mosaic lowers no select of bool vectors."""
+    return (cond & a) | (~cond & b)
+
+
+def _flat(table, nd: int):
+    """Collapse the ``nd`` trailing axes of ``table`` into one."""
+    return table.reshape(table.shape[:table.ndim - nd] + (-1,))
+
+
+def _onehot_sum(oh, t, axis: int = -1):
+    """Contract the one-hot mask ``oh`` against ``t`` over ``axis`` (exact:
+    one hot lane, ``x + 0 == x``).  Bool tables are contracted as int32,
+    since Mosaic lowers neither bool reductions nor bool shape casts."""
+    if t.dtype == jnp.bool_:
+        return _onehot_sum(oh, t.astype(jnp.int32), axis) != 0
+    return jnp.sum(jnp.where(oh, t, jnp.zeros((), t.dtype)), axis=axis)
+
+
+def _take(table, idx, nd: int = 1):
+    """``table[..., idx]`` with ``idx`` a row-major flat index over the
+    ``nd`` trailing axes of ``table``.
+
+    ``table``: ``(..., *dims)``; ``idx``: int ``(..., Q)`` with the same
+    leading axes -> ``(..., Q)`` in ``table.dtype``.  Exact: one hot lane
+    (one-hot lowering) / clamped gather (XLA lowering); call sites mask any
+    slot whose index can be out of range.
     """
+    table = _flat(table, nd)
     if not _ONEHOT_ONLY:
         lead = jnp.broadcast_shapes(table.shape[:-1], idx.shape[:-1])
         return jnp.take_along_axis(
@@ -111,31 +139,57 @@ def _take(table, idx):
             jnp.broadcast_to(idx, lead + idx.shape[-1:]),
             axis=-1, mode="clip")
     oh = _oh_eq(idx, table.shape[-1])          # (..., Q, N)
-    t = table[..., None, :]                    # (..., 1, N)
-    if table.dtype == jnp.bool_:
-        return jnp.any(oh & t, axis=-1)
-    return jnp.sum(jnp.where(oh, t, jnp.zeros((), table.dtype)), axis=-1)
+    if table.dtype == jnp.bool_:               # expand as int32, see above
+        return _onehot_sum(oh, table.astype(jnp.int32)[..., None, :]) != 0
+    return _onehot_sum(oh, table[..., None, :])
 
 
-def _take1(table, idx):
+def first_true(mask):
+    """Index of the first ``True`` along the trailing axis (0 when none) —
+    ``jnp.argmax`` of a bool array, written as a min over an iota because
+    Mosaic lowers index reductions of ``f32`` operands only."""
+    n = mask.shape[-1]
+    iota = lax.broadcasted_iota(jnp.int32, mask.shape, mask.ndim - 1)
+    idx = jnp.min(jnp.where(mask, iota, n), axis=-1)
+    return jnp.where(idx == n, 0, idx)
+
+
+def argmax_first(x):
+    """``jnp.argmax`` over the trailing axis (first maximum) for NaN-free
+    ``x``, in the lowering :func:`first_true` needs."""
+    return first_true(x == jnp.max(x, axis=-1, keepdims=True))
+
+
+def argmin_first(x):
+    """``jnp.argmin`` over the trailing axis (first minimum), NaN-free."""
+    return first_true(x == jnp.min(x, axis=-1, keepdims=True))
+
+
+def _take1(table, idx, nd: int = 1):
     """``table[..., idx]`` for a single per-device index."""
-    return _take(table, idx[..., None])[..., 0]
+    if not _ONEHOT_ONLY:
+        return _take(table, idx[..., None], nd)[..., 0]
+    table = _flat(table, nd)
+    return _onehot_sum(_oh_eq(idx, table.shape[-1]), table)
 
 
-def take_rows(table, idx):
-    """``table[..., idx, :]`` — one row of the second-to-last axis per index.
+def take_rows(table, idx, nd: int = 1):
+    """``table[..., idx, :]`` — one row per index, ``idx`` a row-major flat
+    index over the ``nd`` axes before the last.
 
-    ``table``: ``(..., N, M)``; ``idx``: int ``(...,)`` with leading axes
-    broadcastable against the table's -> ``(..., M)`` in ``table.dtype``.
-    The live-serving transition uses this to pull one device's feature /
-    centroid row out of the flattened ``(K*J*U, ...)`` tables.  Same
-    lowering contract as :func:`_take`: ``take_along_axis`` (clamped) on
-    the XLA frontends, a one-hot iota contraction over the row axis inside
-    Mosaic kernels — bit-exact against each other (one hot lane,
+    ``table``: ``(..., *rows, M)``; ``idx``: int ``(...,)`` with leading
+    axes broadcastable against the table's -> ``(..., M)`` in
+    ``table.dtype``.  The live-serving transition uses this to pull one
+    device's feature / centroid row out of the ``(K, J, U, ...)`` tables.
+    Same lowering contract as :func:`_take`: ``take_along_axis`` (clamped)
+    on the XLA frontends, a one-hot iota contraction over the row axis
+    inside Mosaic kernels — bit-exact against each other (one hot lane,
     ``x + 0 == x``).  A 2-D table with batched indices lowers as a plain
     ``jnp.take`` so the operand is gathered directly instead of being
     broadcast across the batch.
     """
+    table = table.reshape(table.shape[:table.ndim - nd - 1] + (-1,)
+                          + table.shape[-1:])
     if not _ONEHOT_ONLY:
         n = table.shape[-2]
         if table.ndim == 2:
@@ -145,22 +199,8 @@ def take_rows(table, idx):
         ix = jnp.broadcast_to(idx[..., None, None],
                               lead + (1,) + table.shape[-1:])
         return jnp.take_along_axis(t, ix, axis=-2, mode="clip")[..., 0, :]
-    oh = _oh_eq(idx, table.shape[-2])[..., None]       # (..., N, 1)
-    if table.dtype == jnp.bool_:
-        return jnp.any(oh & table, axis=-2)
-    return jnp.sum(jnp.where(oh, table, jnp.zeros((), table.dtype)),
-                   axis=-2)
-
-
-def _flat2(t):
-    """Collapse the two trailing axes (e.g. (..., K, U) -> (..., K*U))."""
-    return t.reshape(t.shape[:-2] + (t.shape[-2] * t.shape[-1],))
-
-
-def _flat3(t):
-    """Collapse the three trailing axes ((..., K, J, U) -> (..., K*J*U))."""
-    return t.reshape(
-        t.shape[:-3] + (t.shape[-3] * t.shape[-2] * t.shape[-1],))
+    oh = _col(_oh_eq(idx, table.shape[-2]))            # (..., N, 1)
+    return _onehot_sum(oh, table, axis=-2)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -394,12 +434,14 @@ def finish_counts(params: StepParams, st: DeviceCarry, mask: jax.Array,
         job = jnp.clip(st.q_job, 0, n_jobs - 1)
         lp = jnp.clip(st.q_last_pred, 0, n_u - 1)
         corr = sched & (st.q_last_pred >= 0) & _take(
-            _flat3(params.correct), (tk * n_jobs + job) * n_u + lp)
+            params.correct, (tk * n_jobs + job) * n_u + lp, 3)
     miss = mask & ~sched
     onehot = _oh_eq(tk, n_tasks)                           # (..., Q, K)
 
     def per_task(m):
-        return jnp.sum(m[..., None] & onehot, axis=-2)
+        # int32 before the trailing expand: Mosaic refuses bool shape casts
+        return jnp.sum(jnp.where(onehot, m.astype(jnp.int32)[..., None], 0),
+                       axis=-2)
 
     return per_task(sched), per_task(corr), per_task(miss)
 
@@ -443,6 +485,7 @@ def admit(params: StepParams, st: DeviceCarry, t, statics: StepStatics,
     """
     q = statics.queue_size
     n_tasks = params.period.shape[-1]
+    n_u = params.unit_time.shape[-1]
     k_iota = lax.broadcasted_iota(jnp.int32, st.next_rel.shape,
                                   st.next_rel.ndim - 1)       # (..., K)
     tr_adm, tr_evict, tr_evict_dl = [], [], []
@@ -458,15 +501,14 @@ def admit(params: StepParams, st: DeviceCarry, t, statics: StepStatics,
         # first, §5.2)
         evictable = st.q_active & (st.q_exited >= 0)
         has_evict = jnp.any(evictable, axis=-1)
-        victim = jnp.argmin(jnp.where(evictable, st.q_deadline, jnp.inf),
-                            axis=-1)
+        victim = argmin_first(jnp.where(evictable, st.q_deadline, jnp.inf))
         evict = releasing & ~has_free & has_evict
-        vmask = evict[..., None] & _oh_eq(victim, q)
+        vmask = _col(evict) & _oh_eq(victim, q)
         d_sched, d_corr, d_miss = finish_counts(params, st, vmask, live)
 
         insert = releasing & (has_free | has_evict)
-        slot = jnp.where(has_free, jnp.argmax(free, axis=-1), victim)
-        ins = insert[..., None] & _oh_eq(slot, q)
+        slot = jnp.where(has_free, first_true(free), victim)
+        ins = _col(insert) & _oh_eq(slot, q)
         dropped = releasing & ~insert   # queue overflow, nothing evictable
         k_hot = k_iota == k
 
@@ -485,7 +527,7 @@ def admit(params: StepParams, st: DeviceCarry, t, statics: StepStatics,
             tr_evict_dl.append(st.q_deadline[victim].astype(_F32))
 
         st = st._replace(
-            next_rel=st.next_rel + (k_hot & releasing[..., None]),
+            next_rel=st.next_rel + (k_hot & _col(releasing)),
             q_active=(st.q_active & ~vmask) | ins,
             q_release=jnp.where(ins, rel_time[..., None], st.q_release),
             q_deadline=jnp.where(
@@ -494,17 +536,18 @@ def admit(params: StepParams, st: DeviceCarry, t, statics: StepStatics,
             q_task=jnp.where(ins, k, st.q_task),
             q_job=jnp.where(ins, nr_k[..., None], st.q_job),
             q_unit=jnp.where(ins, 0, st.q_unit),
-            q_time_left=jnp.where(ins, params.unit_time[..., k, 0][..., None],
-                                  st.q_time_left),
+            q_time_left=jnp.where(
+                ins, _flat(params.unit_time, 2)[..., k * n_u:k * n_u + 1],
+                st.q_time_left),
             q_exited=jnp.where(ins, -1, st.q_exited),
             q_last_pred=jnp.where(ins, -1, st.q_last_pred),
             q_mand_time=jnp.where(ins, -1.0, st.q_mand_time),
             q_margin=jnp.where(ins, 0.0, st.q_margin),
-            q_correct=jnp.where(ins, False, st.q_correct),
-            q_apass=jnp.where(ins, False, st.q_apass),
+            q_correct=st.q_correct & ~ins,
+            q_apass=st.q_apass & ~ins,
             m_scheduled=st.m_scheduled + d_sched,
             m_correct=st.m_correct + d_corr,
-            m_misses=st.m_misses + d_miss + (dropped[..., None] & k_hot),
+            m_misses=st.m_misses + d_miss + (_col(dropped) & k_hot),
         )
     if trace:
         return st, (jnp.stack(tr_adm), jnp.stack(tr_evict),
@@ -556,8 +599,8 @@ def pick_inputs(params: StepParams, st: DeviceCarry, t,
     n_u = params.unit_time.shape[-1]
     tk = jnp.clip(st.q_task, 0, n_tasks - 1)
     u = jnp.clip(st.q_unit, 0, n_u - 1)
-    unit_t = _take(_flat2(params.unit_time), tk * n_u + u)
-    unit_e = _take(_flat2(params.unit_energy), tk * n_u + u)
+    unit_t = _take(params.unit_time, tk * n_u + u, 2)
+    unit_e = _take(params.unit_energy, tk * n_u + u, 2)
     gate_e = jnp.maximum(unit_e / _take(params.fragments, tk),
                          params.e_man[..., None])
     drain = unit_e * (statics.dt / unit_t)
@@ -567,8 +610,8 @@ def pick_inputs(params: StepParams, st: DeviceCarry, t,
         n_jobs = params.margins.shape[-2]
         job = jnp.clip(st.q_job, 0, n_jobs - 1)
         lp = jnp.clip(st.q_last_pred, 0, params.margins.shape[-1] - 1)
-        margin = _take(_flat3(params.margins),
-                       (tk * n_jobs + job) * params.margins.shape[-1] + lp)
+        margin = _take(params.margins,
+                       (tk * n_jobs + job) * params.margins.shape[-1] + lp, 3)
     utility = jnp.where(st.q_last_pred >= 0, margin, 0.0)
     mandatory = st.q_exited < 0
     laxity = st.q_deadline - t
@@ -601,7 +644,7 @@ def select_and_charge(scores, threshold, forced, energy, charge, capacity,
     Uses only iota/arithmetic (no gathers) so the body is Mosaic-safe.
     """
     sel = jnp.where(forced >= 0, forced,
-                    jnp.argmax(scores, axis=-1)).astype(jnp.int32)
+                    argmax_first(scores)).astype(jnp.int32)
     picked = (forced >= 0) | (jnp.max(scores, axis=-1) > threshold)
     # lane-select the chosen slot's energy gate / drain (iota keeps the
     # expression gather-free inside Pallas tiles)
@@ -628,7 +671,7 @@ def pick(params: StepParams, st: DeviceCarry, t, statics: StepStatics,
         params.policy[..., None], st.q_active, laxity, st.q_release,
         utility, mandatory, params.alpha[..., None], params.beta[..., None],
         params.eta[..., None], st.energy[..., None], params.e_opt[..., None],
-        params.persistent[..., None], task_rank)
+        _col(params.persistent), task_rank)
     return select_and_charge(scores, thr[..., 0], forced, st.energy, charge,
                              params.capacity, gate_e, drain)
 
@@ -667,19 +710,19 @@ def apply_step(params: StepParams, st: DeviceCarry, t, sel, picked, run,
     tk_sel = _take1(tk, sel)
 
     u_sel = jnp.clip(_take1(st.q_unit, sel), 0, u_max)
-    frag_t = (_take1(_flat2(params.unit_time), tk_sel * n_u + u_sel)
+    frag_t = (_take1(params.unit_time, tk_sel * n_u + u_sel, 2)
               / _take1(params.fragments, tk_sel))
 
     # power-down / reboot bookkeeping (the initial cold boot counts wasted
     # half-fragment re-execution but not a reboot — matches the scalar path)
     reboot = run & st.was_off
-    was_off = jnp.where(run, False, jnp.where(picked, True, st.was_off))
+    was_off = ~run & (picked | st.was_off)
     idle_inc = jnp.where(picked & ~run, statics.dt, 0.0)
 
     # execute dt of the selected unit
-    time_left = st.q_time_left - jnp.where(run[..., None] & oh,
+    time_left = st.q_time_left - jnp.where(_col(run) & oh,
                                            statics.dt, 0.0)
-    complete = run[..., None] & oh & (time_left <= statics.dt * 1e-3)
+    complete = _col(run) & oh & (time_left <= statics.dt * 1e-3)
 
     u = jnp.clip(st.q_unit, 0, u_max)
     job = jnp.clip(st.q_job, 0, params.passes.shape[-2] - 1)
@@ -691,7 +734,7 @@ def apply_step(params: StepParams, st: DeviceCarry, t, sel, picked, run,
     last_pred = jnp.where(complete, u, st.q_last_pred)
     unit = jnp.where(complete, st.q_unit + 1, st.q_unit)
     time_left = jnp.where(
-        complete, _take(_flat2(params.unit_time), tk * n_u + next_u),
+        complete, _take(params.unit_time, tk * n_u + next_u, 2),
         time_left)
 
     # utility test at the unit boundary (imprecise policies only); tuned
@@ -705,21 +748,21 @@ def apply_step(params: StepParams, st: DeviceCarry, t, sel, picked, run,
             # align the right way up (value-identical on the vmap path,
             # where the outcomes are rank-0 scalars)
             margin_sel = margin_sel[..., None]
-            passed_sel = passed_sel[..., None]
-            correct_sel = correct_sel[..., None]
+            passed_sel = _col(passed_sel)
+            correct_sel = _col(correct_sel)
         passed = jnp.broadcast_to(passed_sel, complete.shape)
         q_margin = jnp.where(complete, margin_sel, st.q_margin)
-        q_correct = jnp.where(complete, correct_sel, st.q_correct)
+        q_correct = select_bool(complete, correct_sel, st.q_correct)
         st = st._replace(q_margin=q_margin, q_correct=q_correct)
     else:
         n_jobs = params.margins.shape[-2]
         kju = (tk * n_jobs + job) * n_u + u
-        passed = jnp.where(
-            params.use_exit_thr[..., None],
-            P.exit_test(_take(_flat3(params.margins), kju),
-                        _take(_flat2(params.exit_thr), tk * n_u + u)),
-            _take(_flat3(params.passes), kju))
-    exit_now = (complete & params.imprecise[..., None]
+        passed = select_bool(
+            _col(params.use_exit_thr),
+            P.exit_test(_take(params.margins, kju, 3),
+                        _take(params.exit_thr, tk * n_u + u, 2)),
+            _take(params.passes, kju, 3))
+    exit_now = (complete & _col(params.imprecise)
                 & (st.q_exited < 0) & passed)
     exited = jnp.where(exit_now, u, st.q_exited)
     # never-confident full execution => the whole DNN was mandatory
@@ -731,7 +774,7 @@ def apply_step(params: StepParams, st: DeviceCarry, t, sel, picked, run,
 
     job_done = complete & (
         (st.q_unit + 1 >= n_units)
-        | (params.is_edfm[..., None] & (exited >= 0))
+        | (_col(params.is_edfm) & (exited >= 0))
     )
     st_done = st._replace(q_last_pred=last_pred, q_mand_time=mand_time)
     d_sched, d_corr, d_miss = finish_counts(params, st_done, job_done, live)
@@ -775,9 +818,9 @@ def apply_step(params: StepParams, st: DeviceCarry, t, sel, picked, run,
         m_scheduled=st.m_scheduled + d_sched,
         m_correct=st.m_correct + d_corr,
         m_misses=st.m_misses + d_miss,
-        m_units=st.m_units + (done_any[..., None] & sel_hot),
+        m_units=st.m_units + (_col(done_any) & sel_hot),
         m_optional=st.m_optional + (
-            (done_any & ~_take1(mandatory, sel))[..., None] & sel_hot),
+            _col(done_any & ~_take1(mandatory, sel)) & sel_hot),
         m_reboots=st.m_reboots + (reboot & (st.m_busy > 0)),
         m_busy=st.m_busy + jnp.where(run, statics.dt, 0.0),
         m_idle=st.m_idle + idle_inc,

@@ -1,4 +1,5 @@
-"""Shared tile-size / padding helpers for the Pallas kernel wrappers.
+"""Shared tile-size / padding helpers for the Pallas kernel wrappers, and
+the one fixed-order sum that kernels and their XLA twins share.
 
 Every kernel in this package tiles one or more axes into VMEM-resident
 blocks.  When an axis size is not a multiple of the block, the kernels used
@@ -55,3 +56,27 @@ def choose_block(size: int, block: int) -> tuple[int, int]:
     bd = min(block, size)
     padded = -(-size // bd) * bd
     return bd, padded
+
+
+def pairwise_sum(x):
+    """Sum over the trailing axis in one fixed order: zero-pad the axis to
+    a power of two, then halve it pairwise (``x[:h] + x[h:]``) until one
+    element is left.
+
+    ``jnp.sum`` leaves the order of the additions to the compiler, and XLA
+    and Mosaic choose different ones on a TPU, so the same f32 reduction
+    rounds differently in a fused kernel and in its XLA twin.  This order
+    is spelled out as elementwise adds, which every backend computes
+    alike.  Zero padding to any larger power of two gives the same result
+    bit for bit (its first halvings add zeros), so operands padded to
+    different widths agree.
+    """
+    n = x.shape[-1]
+    width = 1 << (n - 1).bit_length()
+    if width != n:
+        x = jnp.concatenate(
+            [x, jnp.zeros(x.shape[:-1] + (width - n,), x.dtype)], axis=-1)
+    while x.shape[-1] > 1:
+        h = x.shape[-1] // 2
+        x = x[..., :h] + x[..., h:]
+    return x[..., 0]

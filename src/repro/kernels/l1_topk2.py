@@ -19,17 +19,18 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from ._tiling import choose_block, pad_axis
+from ._tiling import choose_block, pad_axis, pairwise_sum
 
 POS = 1e30  # python scalar: jnp constants would be captured consts in pallas
 
 
 def _l1_topk2_kernel(x_ref, c_ref, d1_ref, d2_ref, idx_ref):
-    """x: (bB, d) VMEM; c: (k, d) VMEM; outputs (bB,) each."""
+    """x: (bB, d) VMEM; c: (k, d) VMEM; outputs (1, bB) each."""
     x = x_ref[...]  # (bB, d)
     c = c_ref[...]  # (k, d)
-    # distances: (bB, k) — elementwise |x - c| reduced over d, k unrolled-free
-    d = jnp.sum(jnp.abs(x[:, None, :] - c[None, :, :]), axis=-1)
+    # distances: (bB, k) — elementwise |x - c| reduced over d in the fixed
+    # order the live-serving classify shares (bit-exact scalar vs fleet)
+    d = pairwise_sum(jnp.abs(x[:, None, :] - c[None, :, :]))
     d1 = jnp.min(d, axis=1)
     idx = jnp.argmin(d, axis=1).astype(jnp.int32)
     k = d.shape[1]
@@ -37,9 +38,9 @@ def _l1_topk2_kernel(x_ref, c_ref, d1_ref, d2_ref, idx_ref):
         jax.nn.one_hot(idx, k, dtype=jnp.bool_), POS, d
     )
     d2 = jnp.min(masked, axis=1)
-    d1_ref[...] = d1
-    d2_ref[...] = d2
-    idx_ref[...] = idx
+    d1_ref[...] = d1.reshape(1, -1)
+    d2_ref[...] = d2.reshape(1, -1)
+    idx_ref[...] = idx.reshape(1, -1)
 
 
 @functools.partial(jax.jit, static_argnames=("block_b", "interpret"))
@@ -67,16 +68,14 @@ def l1_topk2(
             pl.BlockSpec((block_b, d), lambda i: (i, 0)),
             pl.BlockSpec((k, d), lambda i: (0, 0)),  # centroids resident
         ],
-        out_specs=[
-            pl.BlockSpec((block_b,), lambda i: (i,)),
-            pl.BlockSpec((block_b,), lambda i: (i,)),
-            pl.BlockSpec((block_b,), lambda i: (i,)),
-        ],
+        # each block's outputs are one lane-dense row: a 1-D (block_b,)
+        # block would not match XLA's tiling of the (Bp,) result
+        out_specs=[pl.BlockSpec((None, 1, block_b), lambda i: (i, 0, 0))] * 3,
         out_shape=[
-            jax.ShapeDtypeStruct((Bp,), jnp.float32),
-            jax.ShapeDtypeStruct((Bp,), jnp.float32),
-            jax.ShapeDtypeStruct((Bp,), jnp.int32),
+            jax.ShapeDtypeStruct((grid[0], 1, block_b), jnp.float32),
+            jax.ShapeDtypeStruct((grid[0], 1, block_b), jnp.float32),
+            jax.ShapeDtypeStruct((grid[0], 1, block_b), jnp.int32),
         ],
         interpret=interpret,
     )(x, centroids)
-    return d1[:B], d2[:B], idx[:B]
+    return d1.reshape(Bp)[:B], d2.reshape(Bp)[:B], idx.reshape(Bp)[:B]

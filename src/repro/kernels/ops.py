@@ -1,9 +1,9 @@
 """Public jit'd entry points for the Pallas kernels.
 
-On CPU (this container) the kernels execute in ``interpret=True`` mode —
-the kernel body runs as traced JAX ops — which validates tiling/indexing
-logic against the pure-jnp oracles in :mod:`repro.kernels.ref`.  On a real
-TPU backend the same calls compile to Mosaic.
+On the CPU backend the kernels execute in ``interpret=True`` mode — the
+kernel body runs as traced JAX ops — which validates tiling/indexing logic
+against the pure-jnp oracles in :mod:`repro.kernels.ref`.  On a TPU the
+same calls compile to Mosaic; nothing selects interpret mode there.
 
 The ``fleet_*`` wrappers add *fleet-shaped* dispatch for the k-means
 kernels: the online harvest-pattern forecaster (:mod:`repro.adapt.forecast`)
@@ -19,7 +19,6 @@ so the padding never leaks into results.
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -38,18 +37,10 @@ from .rglru_scan import rglru_scan as _rglru_scan
 
 @functools.lru_cache(maxsize=1)
 def _interpret() -> bool:
-    """Should Pallas run in interpret mode on this backend?
+    """Pallas runs in interpret mode on the CPU backend, and only there.
 
-    Pallas compiles natively on TPU (Mosaic) *and* GPU (Triton); only
-    plain-CPU backends need interpret mode.  Cached — the backend cannot
-    change within a process.  ``REPRO_PALLAS_INTERPRET=1`` (or ``0``)
-    overrides the autodetection either way, for debugging compiled-path
-    issues without editing call sites.
-    """
-    env = os.environ.get("REPRO_PALLAS_INTERPRET", "").strip().lower()
-    if env:
-        return env not in ("0", "false", "no", "off")
-    return jax.default_backend() not in ("tpu", "gpu")
+    Cached — the backend cannot change within a process."""
+    return jax.default_backend() == "cpu"
 
 
 def l1_topk2(x, centroids, **kw):
@@ -152,7 +143,7 @@ def fleet_fused_steps(cfg, carry, i0, *, statics, n_steps, **kw):
                               n_steps=n_steps, **kw)
 
 
-def serve_fused_steps(cfg, carry, tables, i0, job0, *, statics, n_steps,
+def serve_fused_steps(cfg, carry, look, i0, job0, *, statics, n_steps,
                       **kw):
     """Whole-segment fused LIVE serving: advance every device ``n_steps``
     timesteps in ONE ``pallas_call`` with the L1-top-2 classify +
@@ -160,5 +151,5 @@ def serve_fused_steps(cfg, carry, tables, i0, job0, *, statics, n_steps,
     (:mod:`repro.kernels.fleet_step`).  Bit-exact vs the serve scan —
     the kernel body IS :func:`repro.serve.fleet_engine.serve_step`."""
     kw.setdefault("interpret", _interpret())
-    return _serve_fused_steps(cfg, carry, tables, i0, job0,
+    return _serve_fused_steps(cfg, carry, look, i0, job0,
                               statics=statics, n_steps=n_steps, **kw)

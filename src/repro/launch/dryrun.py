@@ -87,9 +87,11 @@ def sweep(out_dir: Path, multi_pod: bool, jobs: int, archs=None,
             ]
             if multi_pod:
                 cmd.append("--multi-pod")
+            # the children only lower on placeholder host meshes: keep them
+            # off any accelerator, which one process at a time may hold
             proc = subprocess.Popen(
                 cmd, stdout=subprocess.PIPE, stderr=subprocess.STDOUT,
-                text=True,
+                text=True, env={**os.environ, "JAX_PLATFORMS": "cpu"},
             )
             running.append((proc, tag, out, time.time()))
         done = [r for r in running if r[0].poll() is not None]
@@ -134,6 +136,9 @@ def main() -> None:
 
     if not args.arch or not args.shape:
         ap.error("--arch and --shape required (or --all)")
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     try:
         record = run_one(args.arch, args.shape, args.multi_pod)
     except Exception:

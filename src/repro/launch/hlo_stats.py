@@ -14,17 +14,47 @@ using ring-algorithm transfer factors per op kind:
 where G is the replica-group size parsed from the op's ``replica_groups``.
 The raw sum of result bytes is reported too (``collective_raw_bytes``).
 
-Hardware model (TPU v5e, per chip): 197 TFLOP/s bf16, 819 GB/s HBM,
-~50 GB/s/link ICI (single-link serialization — conservative).
+Hardware model: per-chip peaks keyed by ``jax.Device.device_kind``
+(:data:`PEAKS`); a device missing from the table is an error, never a
+default.  The dry-run targets TPU v5e (:data:`DRYRUN_TARGET`).
 """
 from __future__ import annotations
 
 import re
 from dataclasses import dataclass, field
 
-PEAK_FLOPS = 197e12       # bf16 FLOP/s per chip
-HBM_BW = 819e9            # bytes/s per chip
-ICI_BW = 50e9             # bytes/s per link
+
+@dataclass(frozen=True)
+class Peaks:
+    """Published per-chip peaks."""
+
+    flops: float          # bf16 FLOP/s
+    hbm_bw: float         # HBM bytes/s
+    ici_bw: float         # bytes/s per ICI link (single-link serialization)
+    source: str
+
+
+#: Source for v5e: Google Cloud documentation, "TPU v5e" — 197 TFLOP/s
+#: bf16, 16 GB HBM at 819 GB/s, 1,600 Gbit/s inter-chip interconnect per
+#: chip, taken as 4 links of 400 Gbit/s = 50 GB/s each.
+PEAKS = {
+    "TPU v5 lite": Peaks(flops=197e12, hbm_bw=819e9, ici_bw=50e9,
+                         source='Google Cloud documentation, "TPU v5e"'),
+}
+
+#: the device_kind the dry-run compiles for (the production meshes)
+DRYRUN_TARGET = "TPU v5 lite"
+
+
+def peaks(device_kind: str) -> Peaks:
+    """The :data:`PEAKS` row of ``device_kind``; unknown kinds raise."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}; "
+            f"known: {sorted(PEAKS)}") from None
+
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -136,15 +166,18 @@ def cost_analysis_dict(compiled) -> dict:
 
 def roofline_terms(
     *, flops: float, bytes_accessed: float, ici_bytes: float,
+    device_kind: str,
 ) -> dict:
     """Three per-device roofline terms (seconds) + the dominant one.
 
     ``flops``/``bytes_accessed`` come from the per-device (post-SPMD)
-    module's cost_analysis; ``ici_bytes`` from :func:`collective_stats`.
+    module's cost_analysis; ``ici_bytes`` from :func:`collective_stats`;
+    the peaks are those of ``device_kind`` (:func:`peaks`).
     """
-    compute_s = flops / PEAK_FLOPS
-    memory_s = bytes_accessed / HBM_BW
-    collective_s = ici_bytes / ICI_BW
+    pk = peaks(device_kind)
+    compute_s = flops / pk.flops
+    memory_s = bytes_accessed / pk.hbm_bw
+    collective_s = ici_bytes / pk.ici_bw
     terms = {
         "compute_s": compute_s,
         "memory_s": memory_s,

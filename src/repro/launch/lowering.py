@@ -21,6 +21,7 @@ from repro.train.optimizer import adamw_init
 from . import sharding as shd
 from .hlo_cost import HloCostModel
 from .hlo_stats import (
+    DRYRUN_TARGET,
     cost_analysis_dict,
     memory_analysis_dict,
     model_flops,
@@ -131,7 +132,8 @@ def analyze(result: LoweringResult) -> dict:
     xla_cost = cost_analysis_dict(compiled)
     cost = HloCostModel(compiled.as_text(), n_dev).entry_cost()
     terms = roofline_terms(
-        flops=cost.flops, bytes_accessed=cost.bytes, ici_bytes=cost.ici_bytes
+        flops=cost.flops, bytes_accessed=cost.bytes,
+        ici_bytes=cost.ici_bytes, device_kind=DRYRUN_TARGET,
     )
     mflops = model_flops(
         spec.cfg, spec.step_kind, spec.shape.global_batch, spec.shape.seq_len

@@ -14,27 +14,16 @@ from __future__ import annotations
 from typing import Mapping
 
 import jax
-from jax.sharding import Mesh
-
-try:  # jax >= 0.5: explicit axis types on mesh construction
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - older jax (e.g. 0.4.x containers)
-    AxisType = None
+from jax.sharding import AbstractMesh, AxisType, Mesh
 
 
 def make_mesh(shape, axes) -> Mesh:
-    """``jax.make_mesh`` with Auto axis types where the jax version has them."""
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with Auto axis types."""
     return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_abstract_mesh(shape, axes):
-    """Device-less mesh for spec inference, across jax versions."""
-    from jax.sharding import AbstractMesh
-
-    if AxisType is None:
-        return AbstractMesh(tuple(zip(axes, shape)))
+    """Device-less mesh for spec inference."""
     return AbstractMesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 

@@ -11,13 +11,12 @@ flag on ``benchmarks/run.py``:
   repeated executions with ``jax.block_until_ready`` around every call
   (async dispatch otherwise lets device work leak between timestamps).
 * :func:`trace` — a ``jax.profiler`` trace context writing a TensorBoard-
-  loadable trace directory; degrades to a no-op (with a notice) when the
-  profiler cannot start, so ``--profile`` never breaks a bench lane.
+  loadable trace directory; a profiler that cannot start raises.
 * :func:`roofline_join` — joins a measured steady-state time against the
   loop-aware HLO cost model (:mod:`repro.launch.hlo_cost`) and the device
-  roofline (:func:`repro.launch.hlo_stats.roofline_terms`): modeled FLOPs /
-  bytes, the bound term, and measured-vs-bound ratio — the attribution
-  record behind the vmap-vs-Pallas device-step gap on the ROADMAP.
+  roofline (:func:`repro.launch.hlo_stats.roofline_terms`, with the peaks
+  of the device that ran it): modeled FLOPs / bytes, the bound term, and
+  measured-vs-bound ratio.
 """
 from __future__ import annotations
 
@@ -118,14 +117,12 @@ def roofline_join(meas: Measurement, n_devices: int = 1) -> Measurement:
     compiled = meas.extra.pop("_compiled", None)
     if compiled is None:
         return meas
-    try:
-        hlo = compiled.as_text()
-    except Exception:                      # backend without HLO text access
-        return meas
+    hlo = compiled.as_text()
     cost = HloCostModel(hlo, n_devices).entry_cost()
     ici = collective_stats(hlo, n_devices).ici_bytes
     terms = roofline_terms(flops=cost.flops, bytes_accessed=cost.bytes,
-                           ici_bytes=ici)
+                           ici_bytes=ici,
+                           device_kind=jax.devices()[0].device_kind)
     bound = terms["bound_s"]
     meas.roofline = dict(
         flops=cost.flops,
@@ -157,20 +154,13 @@ def trace(log_dir, enabled: bool = True):
 
     ``enabled=False`` makes it a clean no-op so call sites can thread a
     ``--profile`` flag straight through; a profiler that fails to start
-    (already active, unsupported backend) degrades to a warning instead of
-    failing the bench lane.
+    raises — a run asked to trace never silently runs untraced.
     """
     if not enabled:
         yield None
         return
-    started = False
+    jax.profiler.start_trace(str(log_dir))
     try:
-        jax.profiler.start_trace(str(log_dir))
-        started = True
-    except Exception as e:                 # pragma: no cover - backend-dep
-        print(f"# profiling: trace disabled ({e})")
-    try:
-        yield str(log_dir) if started else None
+        yield str(log_dir)
     finally:
-        if started:
-            jax.profiler.stop_trace()
+        jax.profiler.stop_trace()

@@ -24,7 +24,11 @@ Examples::
         --policy zygarde --eta 0.71 --source solar --requests 40
 
     PYTHONPATH=src python -m repro.launch.serve --engine anytime \
-        --arch xlstm-125m --policy zygarde --requests 24 --deadline 2.5
+        --arch xlstm-125m --reduced --policy zygarde --requests 24 \
+        --deadline 2.5
+
+Without ``--reduced`` the anytime engine runs the config at its published
+widths.
 """
 from __future__ import annotations
 
@@ -36,6 +40,7 @@ import numpy as np
 from repro.core import energy
 from repro.core.agile import AgileCNN
 from repro.data import make_dataset
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serve import Request, ServeConfig, ServeEngine
 from repro.train import train_agile_cnn
 
@@ -91,7 +96,10 @@ def run_scalar(args) -> None:
           f"{corr_pct:.0f}% of scheduled classified correctly")
 
 
-def run_anytime(args) -> None:
+def build_anytime(args):
+    """The model config, random weights from ``--seed``, engine and
+    requests of ``--engine anytime``: ``(cfg, params, engine, requests)``.
+    """
     import dataclasses
 
     import jax
@@ -101,12 +109,14 @@ def run_anytime(args) -> None:
     from repro.serve import (AnytimeConfig, AnytimeRequest,
                              AnytimeServeEngine)
 
-    # CPU-runnable variant of the registered config, deep enough to have
-    # optional units worth skipping
-    cfg = get_config(args.arch).reduced()
-    cfg = dataclasses.replace(
-        cfg, n_layers=max(cfg.n_layers, 4), vocab=min(cfg.vocab, 64),
-        d_model=min(cfg.d_model, 128), exit_every=1)
+    cfg = get_config(args.arch)
+    if args.reduced:
+        # CPU-runnable variant of the registered config, deep enough to
+        # have optional units worth skipping
+        cfg = cfg.reduced()
+        cfg = dataclasses.replace(
+            cfg, n_layers=max(cfg.n_layers, 4), vocab=min(cfg.vocab, 64),
+            d_model=min(cfg.d_model, 128), exit_every=1)
     params = T.init_params(cfg, jax.random.PRNGKey(args.seed))
     policy = {"zygarde": "anytime", "edf": "edf", "edf-m": "edf-m",
               "rr": "anytime"}[args.policy]
@@ -127,6 +137,12 @@ def run_anytime(args) -> None:
             deadline=i * args.period + args.deadline)
         for i in range(args.requests)
     ]
+    return cfg, params, engine, reqs
+
+
+def run_anytime(args) -> None:
+    cfg, _, engine, reqs = build_anytime(args)
+    policy = engine.scfg.policy
     print(f"anytime-serving {len(reqs)} requests on {args.arch} "
           f"({cfg.n_units} units, policy {policy!r}, "
           f"source {args.source}) ...")
@@ -134,7 +150,7 @@ def run_anytime(args) -> None:
     print(json.dumps(res.as_dict(), indent=2))
 
 
-def main() -> None:
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser(
         description="Zygarde serving driver (scalar agile engine or "
                     "anytime big-model engine)")
@@ -144,6 +160,8 @@ def main() -> None:
                     choices=["mnist", "esc10", "cifar100", "vww"])
     ap.add_argument("--arch", default="xlstm-125m",
                     help="registered model config for --engine anytime")
+    ap.add_argument("--reduced", action="store_true",
+                    help="--engine anytime: a CPU-sized variant of --arch")
     ap.add_argument("--policy", default="zygarde",
                     choices=["zygarde", "edf", "edf-m", "rr"])
     ap.add_argument("--eta", type=float, default=0.71)
@@ -155,7 +173,12 @@ def main() -> None:
     ap.add_argument("--deadline", type=float, default=2.0)
     ap.add_argument("--no-adapt", action="store_true")
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args()
+    return ap.parse_args(argv)
+
+
+def main() -> None:
+    args = parse_args()
+    enable_compile_cache()
     if args.engine == "anytime":
         run_anytime(args)
     else:

@@ -29,6 +29,7 @@ from repro.train import make_train_step, save_checkpoint
 from repro.train.optimizer import adamw_init
 
 from . import sharding as shd
+from .compile_cache import enable_compile_cache
 from .mesh import logical_rules, make_host_mesh, make_production_mesh
 
 
@@ -53,6 +54,7 @@ def main() -> None:
     ap.add_argument("--ckpt-path", default="experiments/ckpt/train")
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

@@ -345,8 +345,10 @@ class AnytimeServeEngine:
         return jax.tree.map(jnp.copy, carry)
 
     # ------------------------------------------------------------------ #
-    def _step(self, tables: AnytimeTables, carry: AnytimeCarry,
+    def _step(self, weights, tables: AnytimeTables, carry: AnytimeCarry,
               knobs: AnytimeKnobs, tel_on: bool) -> AnytimeCarry:
+        """One engine step; ``weights`` is ``(params, heads)``, an argument
+        of the jitted segment rather than a constant folded into it."""
         cfg, sc = self.cfg, self.scfg
         B, U, m = sc.batch_slots, self.n_units, self.mandatory
         N = tables.prompt.shape[0]
@@ -385,8 +387,9 @@ class AnytimeServeEngine:
         on = energy >= sc.e_base
 
         def run_model(st):
-            return A.unit_decode_step(cfg, self.params, self.heads, st,
-                                      slot_next, window=sc.window)
+            params, heads = weights
+            return A.unit_decode_step(cfg, params, heads, st, slot_next,
+                                      window=sc.window)
 
         def skip_model(st):
             return (jnp.zeros((U, B, cfg.padded_vocab), _F32), st)
@@ -493,9 +496,10 @@ class AnytimeServeEngine:
     def _segment_fn(self, n_steps: int, tel_on: bool):
         key = (n_steps, tel_on)
         if key not in self._seg_fns:
-            def seg(carry, tables, knobs):
+            def seg(carry, tables, knobs, weights):
                 def body(c, _):
-                    return self._step(tables, c, knobs, tel_on), None
+                    return self._step(weights, tables, c, knobs,
+                                      tel_on), None
                 carry, _ = jax.lax.scan(
                     body, carry, None, length=n_steps)
                 return carry
@@ -535,7 +539,7 @@ class AnytimeServeEngine:
             if n_steps == 0:
                 continue
             carry = self._segment_fn(n_steps, tel_on)(
-                carry, tables, knobs)
+                carry, tables, knobs, (self.params, self.heads))
             if hook is not None:
                 new = hook(seg, carry, knobs)
                 if new is not None:
@@ -577,7 +581,8 @@ class AnytimeServeEngine:
             carry = self.init_carry(tables)
 
             def body(c, _):
-                return self._step(tables, c, knobs, False), None
+                return self._step((self.params, self.heads), tables, c,
+                                  knobs, False), None
 
             carry, _ = jax.lax.scan(body, carry, None, length=T_total)
             ontime = carry.req_status == 2

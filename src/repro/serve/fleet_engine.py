@@ -68,6 +68,7 @@ from ..core.energy import Capacitor, Harvester
 from ..core.scheduler import JobProfile, TaskSpec
 from ..fleet import grid
 from ..fleet.simulator import finalize_fleet
+from ..kernels._tiling import pairwise_sum
 from ..telemetry import state as T
 from ..telemetry import trace as T_trace
 from ..fleet.state import (
@@ -207,7 +208,8 @@ def classify_unit(bank: ServeBank, tables: ServeTables, tk, u, job):
     """Single-row live classification for one device's completing unit.
 
     The pure-jnp row variant of :func:`repro.core.kmeans.classify`: same
-    elementwise ``|x - c|`` innermost-axis reduction, same one-hot-masked
+    elementwise ``|x - c|`` reduced in the same fixed order
+    (:func:`repro.kernels._tiling.pairwise_sum`), same one-hot-masked
     second minimum (mask value :data:`_POS`), same scale-free margin — so
     the result is bit-identical to the scalar path's ``l1_topk2`` kernel
     (interpret mode) on the same operands (asserted in
@@ -217,7 +219,7 @@ def classify_unit(bank: ServeBank, tables: ServeTables, tk, u, job):
     fsel = tables.sel_feats[tk, job, u]                       # (S,)
     idxs = tables.fidx[tk, u]                                 # (S,)
     csel = bank.centroids[tk, u][:, idxs]                     # (C, S)
-    dist = jnp.sum(jnp.abs(fsel[None, :] - csel), axis=-1)    # (C,)
+    dist = pairwise_sum(jnp.abs(fsel[None, :] - csel))        # (C,)
     d1 = jnp.min(dist)
     ci = jnp.argmin(dist).astype(_I32)
     d2 = jnp.min(jnp.where(jnp.arange(dist.shape[0]) == ci, _POS, dist))
@@ -226,51 +228,90 @@ def classify_unit(bank: ServeBank, tables: ServeTables, tk, u, job):
     return margin, ci, pred
 
 
-def _classify_rows(bank: ServeBank, tables: ServeTables, tk, u, job):
+def select_centroids(centroids, fidx):
+    """The centroid bank restricted to each classifier's selected feature
+    dims: ``(..., K, U, C, F)`` gathered by ``fidx`` ``(K, U, S)`` ->
+    ``(..., K, U, C, S)`` — the only columns classification reads."""
+    idx = jnp.broadcast_to(fidx[..., None, :],
+                           centroids.shape[:-1] + fidx.shape[-1:])
+    return jnp.take_along_axis(centroids, idx, axis=-1)
+
+
+class ServeLookup(NamedTuple):
+    """What :func:`serve_step` reads, in the flat-row form of its lookups.
+
+    Built outside the step (:func:`serve_lookup`) so the step itself never
+    reshapes or gathers a table: inside the fused Pallas kernel a reshape
+    that splits or merges the tiled axes is refused, and a gather over the
+    full feature width cannot run in a device tile.  The ``[D,]`` axis is
+    present on the per-device request streams / banks only.
+    """
+
+    feat_rows: jax.Array     # ([D,] K*J*U, S) f32 — selected-dim features
+    cent_rows: jax.Array     # ([D,] K*U*C, S) f32 — centroids, same dims
+    labels: jax.Array        # ([D,] K*J) i32 — request ground truth
+    clabels: jax.Array       # (K*U*C,) i32 — cluster -> class label
+    thr: jax.Array           # (K*U,) f32 — bank utility thresholds
+
+
+def serve_lookup(tables: ServeTables, centroids) -> ServeLookup:
+    """:class:`ServeLookup` of ``tables`` against the bank ``centroids``
+    (``([D,] K, U, C, F)``)."""
+    sel = select_centroids(centroids, tables.fidx)
+    sf = tables.sel_feats
+    return ServeLookup(
+        feat_rows=sf.reshape(sf.shape[:-4] + (-1, sf.shape[-1])),
+        cent_rows=sel.reshape(sel.shape[:-4] + (-1, sel.shape[-1])),
+        labels=tables.labels.reshape(tables.labels.shape[:-2] + (-1,)),
+        clabels=tables.clabels.reshape(-1),
+        thr=tables.thr.reshape(-1))
+
+
+def _classify_rows(look: ServeLookup, n_tasks: int, tk, u, job):
     """Batch-polymorphic twin of :func:`classify_unit`.
 
     ``tk``/``u``/``job`` carry arbitrary leading axes (the scan passes
-    ``(D,)``, the fused kernel a ``(bd,)`` tile); ``bank``/feature leaves
-    may or may not share those leading axes (shared vs per-device modes).
-    All gathers go through the dual-lowering :func:`repro.core.step.take_rows`
-    / ``_take`` helpers so the same trace compiles as ``take_along_axis``
-    under XLA and as one-hot iota contractions inside Mosaic — and the
-    arithmetic (innermost L1 reduction, first-min tie-break, one-hot-masked
-    second minimum, scale-free margin) matches :func:`classify_unit`
-    bit-for-bit.
+    ``(D,)``, the fused kernel a ``(bd,)`` tile); the :class:`ServeLookup`
+    leaves may or may not share those leading axes (shared vs per-device
+    modes).  All gathers go through the dual-lowering
+    :func:`repro.core.step.take_rows` / ``_take1`` helpers so the same
+    trace compiles as ``take_along_axis`` under XLA and as one-hot iota
+    contractions inside Mosaic — and the arithmetic (fixed-order L1
+    reduction over the same ``(..., C, S)`` operand, first-min tie-break,
+    one-hot-masked second minimum, scale-free margin) matches
+    :func:`classify_unit` bit-for-bit.
     """
-    K = tables.fidx.shape[-3]
-    Ub = tables.fidx.shape[-2]
-    Wl = tables.labels.shape[-1]
-    C = bank.centroids.shape[-2]
-    F = bank.centroids.shape[-1]
+    Ub = look.thr.shape[-1] // n_tasks
+    Wl = look.labels.shape[-1] // n_tasks
+    C = look.clabels.shape[-1] // look.thr.shape[-1]
     ku = tk * Ub + u
-    sf = tables.sel_feats.reshape(
-        tables.sel_feats.shape[:-4] + (K * Wl * Ub,
-                                       tables.sel_feats.shape[-1]))
-    fsel = S.take_rows(sf, (tk * Wl + job) * Ub + u)          # (..., S)
-    idxs = S.take_rows(
-        tables.fidx.reshape(tables.fidx.shape[:-3]
-                            + (K * Ub, tables.fidx.shape[-1])), ku)
-    crow = S.take_rows(
-        bank.centroids.reshape(bank.centroids.shape[:-4] + (K * Ub, C * F)),
-        ku)
-    crow = crow.reshape(crow.shape[:-1] + (C, F))             # (..., C, F)
-    csel = S._take(crow, idxs[..., None, :])                  # (..., C, S)
-    dist = jnp.sum(jnp.abs(fsel[..., None, :] - csel), axis=-1)
+    fsel = S.take_rows(look.feat_rows, (tk * Wl + job) * Ub + u)   # (.., S)
+    # the C centroid rows of (task, unit), assembled row by row: a one-hot
+    # row pick per cluster, placed with x + 0 == x (exact)
+    shape = fsel.shape[:-1] + (C,) + fsel.shape[-1:]
+    iota_c = lax.broadcasted_iota(_I32, shape, len(shape) - 2)
+    csel = jnp.zeros(shape, _F32)
+    for c in range(C):
+        row = S.take_rows(look.cent_rows, ku * C + c)
+        csel = csel + jnp.where(iota_c == c, row[..., None, :], 0.0)
+    dist = pairwise_sum(jnp.abs(fsel[..., None, :] - csel))
     d1 = jnp.min(dist, axis=-1)
-    ci = jnp.argmin(dist, axis=-1).astype(_I32)
-    iota_c = lax.broadcasted_iota(_I32, dist.shape, dist.ndim - 1)
-    d2 = jnp.min(jnp.where(iota_c == ci[..., None], _POS, dist), axis=-1)
+    ci = S.argmin_first(dist).astype(_I32)
+    iota_d = lax.broadcasted_iota(_I32, dist.shape, dist.ndim - 1)
+    d2 = jnp.min(jnp.where(iota_d == ci[..., None], _POS, dist), axis=-1)
     margin = (d2 - d1) / jnp.maximum(d1 + d2, 1e-9)
-    pred = S._take1(
-        tables.clabels.reshape(tables.clabels.shape[:-3] + (K * Ub * C,)),
-        ku * C + ci)
+    pred = S._take1(look.clabels, ku * C + ci)
     return margin, ci, pred
 
 
-def serve_step(cfg: FleetConfig, tables: ServeTables, dev, bank: ServeBank,
-               log: ServeLog, t, job0, *, statics: FleetStatics):
+def flat_log(log: ServeLog) -> ServeLog:
+    """``(..., K, J)`` log leaves -> ``(..., K*J)``, the form
+    :func:`serve_step` updates."""
+    return ServeLog(*[l.reshape(l.shape[:-2] + (-1,)) for l in log])
+
+
+def serve_step(cfg: FleetConfig, look: ServeLookup, dev, log: ServeLog, t,
+               job0, *, statics: FleetStatics):
     """One live-serving timestep for every device — batch-polymorphic.
 
     The whole-fleet twin of :meth:`FleetServeEngine._scan_steps`'s per-step
@@ -278,9 +319,10 @@ def serve_step(cfg: FleetConfig, tables: ServeTables, dev, bank: ServeBank,
     trace runs as the scan body (XLA, leading ``(D,)``) *and* inside the
     fused Pallas segment kernel (a ``(bd,)`` VMEM tile under
     :func:`repro.core.step.onehot_lowering`): admit → drop-expired → pick →
-    classify against the bank → inject ``(margin, passed, correct)`` into
-    :func:`repro.core.step.apply_step` → latch the utility pass → write the
-    per-job outcome log.
+    classify against the bank (``look``, :func:`serve_lookup`) → inject
+    ``(margin, passed, correct)`` into :func:`repro.core.step.apply_step`
+    → latch the utility pass → write the per-job outcome log, whose leaves
+    come and go flat (:func:`flat_log`).
 
     ``job0`` (``(K,)`` i32) rebases global job ids into the streamed table
     window: row ``j`` of the ``(..., K, Wl)`` feature/label/log leaves holds
@@ -297,8 +339,8 @@ def serve_step(cfg: FleetConfig, tables: ServeTables, dev, bank: ServeBank,
     K = cfg.period.shape[-1]
     n_u = cfg.unit_time.shape[-1]
     Ue = cfg.exit_thr.shape[-1]
-    Wl = tables.labels.shape[-1]
-    Ub = tables.fidx.shape[-2]
+    Wl = look.labels.shape[-1] // K
+    Ub = look.thr.shape[-1] // K
     Q = statics.queue_size
 
     dev = S.admit(cfg, dev, t, statics, True)
@@ -316,16 +358,12 @@ def serve_step(cfg: FleetConfig, tables: ServeTables, dev, bank: ServeBank,
     apass_pre = S._take1(dev.q_apass, sel)
     ddl = S._take1(dev.q_deadline, sel)
     nu_sel = S._take1(cfg.n_units, tk)
-    thr_cfg = S._take1(S._flat2(cfg.exit_thr), tk * Ue + u)
+    thr_cfg = S._take1(cfg.exit_thr, tk * Ue + u, 2)
 
-    margin, ci, pred = _classify_rows(bank, tables, tk, u, job)
-    label = S._take1(
-        tables.labels.reshape(tables.labels.shape[:-2] + (K * Wl,)),
-        tk * Wl + job)
-    correct = pred == label
-    pass_bank = margin > S._take1(
-        tables.thr.reshape(tables.thr.shape[:-2] + (K * Ub,)), tk * Ub + u)
-    passed = jnp.where(cfg.use_exit_thr, margin > thr_cfg, pass_bank)
+    margin, ci, pred = _classify_rows(look, K, tk, u, job)
+    correct = pred == S._take1(look.labels, tk * Wl + job)
+    pass_bank = margin > S._take1(look.thr, tk * Ub + u)
+    passed = S.select_bool(cfg.use_exit_thr, margin > thr_cfg, pass_bank)
 
     dev = S.apply_step(cfg, dev, t, sel, picked, run, e_new, statics, True,
                        (margin, passed, correct))
@@ -336,7 +374,7 @@ def serve_step(cfg: FleetConfig, tables: ServeTables, dev, bank: ServeBank,
     first_pass = complete & pass_bank & ~apass_pre
     oh = S._oh_eq(sel, Q)
     dev = dev._replace(
-        q_apass=dev.q_apass | (oh & (complete & pass_bank)[..., None]))
+        q_apass=dev.q_apass | (oh & S._col(complete & pass_bank)))
 
     # per-job outcome log (mirrors apply_step's completion math)
     exit_now = complete & cfg.imprecise & (exited_pre < 0) & passed
@@ -344,15 +382,13 @@ def serve_step(cfg: FleetConfig, tables: ServeTables, dev, bank: ServeBank,
     full_mand = complete & (exited_mid < 0) & (u + 1 >= nu_sel)
     mand_now = exit_now | full_mand
     sched_now = (t + statics.dt) <= ddl
-    nd = complete.ndim
-    kk = lax.broadcasted_iota(_I32, complete.shape + (K, Wl), nd)
-    jj = lax.broadcasted_iota(_I32, complete.shape + (K, Wl), nd + 1)
-    m_jd = (complete[..., None, None]
-            & (kk == tk[..., None, None]) & (jj == job[..., None, None]))
+    m_jd = S._col(complete) & S._oh_eq(tk * Wl + job, K * Wl)
 
     def put(old, new, mask=None):
-        mm = m_jd if mask is None else m_jd & mask[..., None, None]
-        return jnp.where(mm, new[..., None, None], old)
+        mm = m_jd if mask is None else m_jd & S._col(mask)
+        if old.dtype == jnp.bool_:
+            return S.select_bool(mm, S._col(new), old)
+        return jnp.where(mm, new[..., None], old)
 
     log = ServeLog(
         units=put(log.units, u + 1),
@@ -400,10 +436,7 @@ def _shift_log(log: ServeLog, shift):
 def _device_peak_bytes() -> int:
     """Peak live device bytes, or 0 where the backend keeps no memory
     statistics (plain-CPU ``memory_stats()`` returns ``None``)."""
-    try:
-        stats = jax.local_devices()[0].memory_stats()
-    except Exception:
-        return 0
+    stats = jax.local_devices()[0].memory_stats()
     if not stats:
         return 0
     return int(stats.get("peak_bytes_in_use", 0))
@@ -569,10 +602,11 @@ class FleetServeEngine:
         ) for s in seeds]
         fleet_cfg = grid.stack_configs(devs)
 
+        # one shared stream is featurized once, not once per device
         feats = [build_feature_tables(
             self.models, s, self.meta, self._bank_tables,
             feature_batch=self.feature_batch, n_jobs=max(n_jobs))
-            for s in streams]
+            for s in (streams if per_dev else streams[:1])]
         if per_dev:
             stacked = {k: jnp.asarray(np.stack([f[k] for f in feats]))
                        for k in feats[0]}
@@ -754,11 +788,18 @@ class FleetServeEngine:
                 jnp.any(first_pass), _upd, lambda args: args[0],
                 (bank, x_full, tk, u, ci, first_pass))
 
+        look0 = serve_lookup(tables, carry.bank.centroids)
+
         def step(carry, i):
             dev, bank, log = carry
             t = i.astype(_F32) * statics.dt
-            dev, log, (first_pass, tk, u, job, ci) = serve_step(
-                cfg, tables, dev, bank, log, t, job0, statics=statics)
+            # an adapting bank moves every step; a frozen one is selected
+            # once, outside the scan
+            look = (serve_lookup(tables, bank.centroids) if adapt
+                    else look0)
+            dev, flog, (first_pass, tk, u, job, ci) = serve_step(
+                cfg, look, dev, flat_log(log), t, job0, statics=statics)
+            log = ServeLog(*[f.reshape(l.shape) for f, l in zip(flog, log)])
             if adapt:
                 bank = adapt_bank(bank, tk, u, job, ci, first_pass)
             new_carry = ServeCarry(dev=dev, bank=bank, log=log)
@@ -953,7 +994,8 @@ class FleetServeEngine:
                 from ..kernels import ops
 
                 out = ops.serve_fused_steps(
-                    cfg, out, tables, jnp.int32(i0),
+                    cfg, out, serve_lookup(tables, out.bank.centroids),
+                    jnp.int32(i0),
                     jnp.zeros((len(self.models),), _I32),
                     statics=statics, n_steps=n, shared_bank=shared,
                     per_dev_tables=per_dev)
@@ -1085,7 +1127,7 @@ class FleetServeEngine:
         feats = [build_feature_tables(
             self.models, s, self.meta, self._bank_tables,
             feature_batch=self.feature_batch, n_jobs=max(base_len))
-            for s in streams]
+            for s in (streams if per_dev else streams[:1])]
         if per_dev:
             base = {k: np.stack([f[k] for f in feats]) for k in feats[0]}
         else:
@@ -1108,7 +1150,8 @@ class FleetServeEngine:
             from ..kernels import ops
 
             return ops.serve_fused_steps(
-                cfg, carry, tables, i0, job0, statics=statics,
+                cfg, carry, serve_lookup(tables, carry.bank.centroids), i0,
+                job0, statics=statics,
                 n_steps=n_steps, shared_bank=shared,
                 per_dev_tables=per_dev_tables)
         return self._scan_steps(cfg, tables, carry, i0, None, job0,

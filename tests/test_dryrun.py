@@ -15,8 +15,6 @@ import pytest
 from _subproc import sub_env
 
 SUB = """
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import json
 import jax
 
@@ -42,7 +40,7 @@ def run_sub(arch, shape):
     code = SUB.format(arch=arch, shape=shape)
     out = subprocess.run(
         [sys.executable, "-c", textwrap.dedent(code)],
-        capture_output=True, text=True, timeout=900, env=sub_env(),
+        capture_output=True, text=True, timeout=900, env=sub_env(host_devices=8),
     )
     assert out.returncode == 0, out.stderr[-3000:]
     line = [l for l in out.stdout.splitlines() if l.startswith("RESULT")][-1]
@@ -68,7 +66,7 @@ def test_long_500k_skip_is_honoured():
     code = SUB.format(arch="seamless-m4t-medium", shape="long_500k")
     out = subprocess.run(
         [sys.executable, "-c", textwrap.dedent(code)],
-        capture_output=True, text=True, timeout=900, env=sub_env(),
+        capture_output=True, text=True, timeout=900, env=sub_env(host_devices=8),
     )
     assert out.returncode != 0
     assert "ShapeSkip" in out.stderr or "skips long_500k" in out.stderr

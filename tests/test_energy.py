@@ -2,7 +2,8 @@
 capacitor, schedulability — unit + hypothesis property tests."""
 import numpy as np
 import pytest
-from _hypothesis_fallback import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core import energy
 

@@ -235,8 +235,6 @@ def test_chrt_clock_maps_to_fleet_drift():
 # --------------------------------------------------------------------------- #
 
 _SHARD_SUB = """
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import numpy as np
 from repro import fleet
 from repro.core import energy
@@ -298,7 +296,8 @@ def test_sharded_sweep_matches_unsharded_4dev():
     subprocess) is bit-identical to the single-device call."""
     out = subprocess.run(
         [sys.executable, "-c", textwrap.dedent(_SHARD_SUB)],
-        capture_output=True, text=True, timeout=600, env=sub_env(),
+        capture_output=True, text=True, timeout=600,
+        env=sub_env(host_devices=4),
     )
     assert out.returncode == 0, out.stderr[-3000:]
     assert "SHARD_OK 6" in out.stdout

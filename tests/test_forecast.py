@@ -29,7 +29,8 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from _hypothesis_fallback import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 from repro import adapt, fleet
 from repro.core import energy, kmeans
 from repro.core.scheduler import JobProfile, TaskSpec

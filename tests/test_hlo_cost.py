@@ -5,6 +5,7 @@ import numpy as np
 import pytest
 
 from repro.launch.hlo_cost import HloCostModel, analyze_hlo
+from repro.launch.hlo_stats import DRYRUN_TARGET, peaks, roofline_terms
 
 
 def compile_text(fn, *args):
@@ -120,3 +121,16 @@ def test_analyze_hlo_dict_keys():
     for k in ("flops", "dot_flops", "bytes", "ici_bytes", "coll_counts"):
         assert k in d
     assert d["ici_bytes"] == 0.0  # single device: no collectives
+
+
+def test_roofline_peaks_keyed_by_device_kind():
+    """The roofline divides by the peaks of the device kind it is given;
+    a kind with no published row raises instead of borrowing another's."""
+    pk = peaks(DRYRUN_TARGET)
+    terms = roofline_terms(flops=pk.flops, bytes_accessed=pk.hbm_bw / 2,
+                           ici_bytes=0.0, device_kind=DRYRUN_TARGET)
+    assert terms["compute_s"] == pytest.approx(1.0)
+    assert terms["memory_s"] == pytest.approx(0.5)
+    with pytest.raises(ValueError, match="no published peaks"):
+        roofline_terms(flops=1.0, bytes_accessed=1.0, ici_bytes=0.0,
+                       device_kind="cpu")

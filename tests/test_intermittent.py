@@ -2,7 +2,8 @@
 run-with-power-failures == run-without, bit-exactly."""
 import numpy as np
 import pytest
-from _hypothesis_fallback import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 import jax.numpy as jnp
 

@@ -1,7 +1,8 @@
 """Semi-supervised k-means classifier bank (paper §4.3)."""
 import numpy as np
 import pytest
-from _hypothesis_fallback import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 import jax
 import jax.numpy as jnp

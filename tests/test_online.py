@@ -18,7 +18,8 @@ from __future__ import annotations
 import numpy as np
 import pytest
 
-from _hypothesis_fallback import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 from repro import adapt, fleet
 from repro.core import energy
 

@@ -16,13 +16,11 @@ Properties:
   threshold does NOT exit, matching the calibration curve's ``margin > t``);
 * float-vs-jnp-vs-``(D, Q)``-array agreement — the same expressions give
   the same numbers for python scalars, jnp scalars and batched arrays.
-
-Wired through ``tests/_hypothesis_fallback`` so the suite still collects
-(with these marked skipped) when the ``test`` extra is absent.
 """
 import numpy as np
 import pytest
-from _hypothesis_fallback import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 import jax.numpy as jnp
 
@@ -279,3 +277,20 @@ def test_priority_fns_float_jnp_agree(laxity, util, alpha, beta):
     e = P.edf_key(laxity, util)
     assert float(P.edf_key(jnp.float32(laxity), jnp.float32(util))) == (
         pytest.approx(e, rel=1e-4, abs=1e-4))
+
+
+@given(st.lists(st.integers(-3, 3), min_size=1, max_size=17),
+       st.sampled_from([-np.inf, 0.0]))
+@settings(max_examples=50, deadline=None)
+def test_index_reductions_match_jnp(vals, fill):
+    """The step core's Mosaic-safe index reductions give ``jnp.argmax`` /
+    ``jnp.argmin``'s answer, first index on ties (small integer values
+    force ties), including all-False masks and ``-inf`` rows."""
+    from repro.core import step as S
+
+    x = jnp.asarray(np.array(vals, np.float32))
+    x = jnp.where(x < -2, fill, x)
+    assert int(S.argmax_first(x)) == int(jnp.argmax(x))
+    assert int(S.argmin_first(x)) == int(jnp.argmin(x))
+    for mask in (x > 0, x > 99):
+        assert int(S.first_true(mask)) == int(jnp.argmax(mask))

@@ -2,7 +2,8 @@
 invariants, and the paper's qualitative claims on synthetic workloads."""
 import numpy as np
 import pytest
-from _hypothesis_fallback import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core import energy
 from repro.core.scheduler import (

@@ -1,6 +1,7 @@
 """Property tests for the sharding-spec layer (hypothesis)."""
 import numpy as np
-from _hypothesis_fallback import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from jax.sharding import PartitionSpec as P
 
