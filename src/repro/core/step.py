@@ -472,6 +472,7 @@ class StepTrace(NamedTuple):
     complete_dl: jax.Array  # f32: completed slot q_deadline
 
 
+@jax.named_scope("admit")
 def admit(params: StepParams, st: DeviceCarry, t, statics: StepStatics,
           live: bool = False, trace: bool = False):
     """Admit at most one released job per task (the builder asserts
@@ -555,6 +556,7 @@ def admit(params: StepParams, st: DeviceCarry, t, statics: StepStatics,
     return st
 
 
+@jax.named_scope("expire")
 def drop_expired(params: StepParams, st: DeviceCarry, t,
                  live: bool = False, trace: bool = False,
                  q_active_pre=None):
@@ -657,6 +659,7 @@ def select_and_charge(scores, threshold, forced, energy, charge, capacity,
     return sel, picked, run, e_new
 
 
+@jax.named_scope("pick")
 def pick(params: StepParams, st: DeviceCarry, t, statics: StepStatics,
          live: bool = False):
     """Priority-argmax + fused capacitor charge/discharge (pure-jnp path).
@@ -676,6 +679,7 @@ def pick(params: StepParams, st: DeviceCarry, t, statics: StepStatics,
                              params.capacity, gate_e, drain)
 
 
+@jax.named_scope("apply")
 def apply_step(params: StepParams, st: DeviceCarry, t, sel, picked, run,
                e_new, statics: StepStatics, live: bool = False,
                outcomes=None, trace: bool = False, q_active_pre=None,

@@ -28,6 +28,7 @@ import jax.numpy as jnp
 from ..core import policy as P
 from ..core.energy import PERSISTENT, Capacitor, Harvester
 from ..core.scheduler import Clock, SimConfig, TaskSpec
+from ..telemetry.spans import span
 from .state import FleetConfig, FleetStatics
 
 _F32 = np.float32
@@ -215,9 +216,10 @@ def sample_events(harvester: Harvester, horizon: float, seed: int) -> np.ndarray
 def stack_configs(devices: Sequence[dict]) -> FleetConfig:
     """Stack per-device dicts into a FleetConfig of (D, ...) jnp arrays."""
     fields = FleetConfig._fields
-    return FleetConfig(**{
-        f: jnp.asarray(np.stack([d[f] for d in devices])) for f in fields
-    })
+    with span("fleet.build.stack"):
+        return FleetConfig(**{
+            f: jnp.asarray(np.stack([d[f] for d in devices])) for f in fields
+        })
 
 
 def from_sim_config(
@@ -303,40 +305,43 @@ class SweepGrid:
 
 def build(grid: SweepGrid) -> tuple[FleetConfig, FleetStatics, list[dict]]:
     """Materialise the grid as a FleetConfig + per-device metadata rows."""
-    points = list(grid.points())
-    if not points:
-        raise ValueError("empty sweep grid")
-    tasks = grid.tasks
-    slot_lens = {pt["harvester"].slot_s for pt in points}
-    if len(slot_lens) != 1:
-        raise ValueError("all harvesters in one sweep must share slot_s")
-    dt = _check_dt(_default_dt(tasks) if grid.dt is None else grid.dt, tasks)
-    statics = FleetStatics(queue_size=grid.queue_size, dt=dt,
-                           horizon=grid.horizon, slot_s=slot_lens.pop())
+    with span("fleet.build"):
+        points = list(grid.points())
+        if not points:
+            raise ValueError("empty sweep grid")
+        tasks = grid.tasks
+        slot_lens = {pt["harvester"].slot_s for pt in points}
+        if len(slot_lens) != 1:
+            raise ValueError("all harvesters in one sweep must share slot_s")
+        dt = _check_dt(
+            _default_dt(tasks) if grid.dt is None else grid.dt, tasks)
+        statics = FleetStatics(queue_size=grid.queue_size, dt=dt,
+                               horizon=grid.horizon, slot_s=slot_lens.pop())
 
-    events_cache: dict[tuple[int, int], np.ndarray] = {}
-    devices, meta = [], []
-    for pt in points:
-        key = (pt["harvester_idx"], pt["seed"])
-        if key not in events_cache:
-            events_cache[key] = sample_events(
-                pt["harvester"], grid.horizon, pt["seed"])
-        devices.append(device_config(
-            tasks, pt["harvester"], pt["eta"], pt["capacitor"],
-            policy=pt["policy"], horizon=grid.horizon,
-            events=events_cache[key],
-            e_opt_fraction=grid.e_opt_fraction, e_man=grid.e_man,
-            start_charged=grid.start_charged,
-            clock_drift=pt["clock_drift"],
-        ))
-        meta.append(dict(
-            policy=pt["policy"], eta=pt["eta"],
-            harvester=pt["harvester"].name, seed=pt["seed"],
-            capacitance_f=pt["capacitor"].capacitance_f,
-            clock_drift=pt["clock_drift"],
-            n_tasks=len(tasks),
-        ))
-    return stack_configs(devices), statics, meta
+        events_cache: dict[tuple[int, int], np.ndarray] = {}
+        devices, meta = [], []
+        with span("fleet.build.configs"):
+            for pt in points:
+                key = (pt["harvester_idx"], pt["seed"])
+                if key not in events_cache:
+                    events_cache[key] = sample_events(
+                        pt["harvester"], grid.horizon, pt["seed"])
+                devices.append(device_config(
+                    tasks, pt["harvester"], pt["eta"], pt["capacitor"],
+                    policy=pt["policy"], horizon=grid.horizon,
+                    events=events_cache[key],
+                    e_opt_fraction=grid.e_opt_fraction, e_man=grid.e_man,
+                    start_charged=grid.start_charged,
+                    clock_drift=pt["clock_drift"],
+                ))
+                meta.append(dict(
+                    policy=pt["policy"], eta=pt["eta"],
+                    harvester=pt["harvester"].name, seed=pt["seed"],
+                    capacitance_f=pt["capacitor"].capacitance_f,
+                    clock_drift=pt["clock_drift"],
+                    n_tasks=len(tasks),
+                ))
+        return stack_configs(devices), statics, meta
 
 
 def sweep(grid: SweepGrid, use_pallas=None, mesh=None, mode=None):

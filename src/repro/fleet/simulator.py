@@ -60,6 +60,7 @@ from ..core import step as S
 from ..telemetry import export as T_export
 from ..telemetry import state as T
 from ..telemetry import trace as T_trace
+from ..telemetry.spans import span
 from .state import DeviceState, FleetConfig, FleetResult, FleetStatics, \
     init_state
 
@@ -352,20 +353,22 @@ def simulate_fleet(cfg: FleetConfig, statics: FleetStatics,
     FleetResult is bit-exact either way.
     """
     mode = _resolve_mode(mode, use_pallas)
-    if mode == "fused":
-        if telemetry is not None:
-            raise ValueError(
-                "mode='fused' does not support telemetry; use mode='vmap'")
-        return _simulate_fleet_fused(cfg, statics)
-    use_pallas = mode == "pallas"
-    if telemetry is None:
-        return _simulate_fleet_plain(cfg, statics, use_pallas)
-    res, tel, ring = _simulate_fleet_tel(cfg, statics, use_pallas, telemetry)
-    if ring is not None:
-        tel = T_trace.fold_events_host(
-            _pack_spec(cfg, statics, tel), tel,
-            tuple(np.asarray(col) for col in ring), 0, statics.dt)
-    return res, tel
+    if mode == "fused" and telemetry is not None:
+        raise ValueError(
+            "mode='fused' does not support telemetry; use mode='vmap'")
+    with span("fleet.simulate"):
+        if mode == "fused":
+            return _simulate_fleet_fused(cfg, statics)
+        use_pallas = mode == "pallas"
+        if telemetry is None:
+            return _simulate_fleet_plain(cfg, statics, use_pallas)
+        res, tel, ring = _simulate_fleet_tel(cfg, statics, use_pallas,
+                                             telemetry)
+        if ring is not None:
+            tel = T_trace.fold_events_host(
+                _pack_spec(cfg, statics, tel), tel,
+                tuple(np.asarray(col) for col in ring), 0, statics.dt)
+        return res, tel
 
 
 @functools.partial(jax.jit,

@@ -211,6 +211,7 @@ def fleet_fused_steps(
         out_shape=[jax.ShapeDtypeStruct(t.shape, t.dtype) for t in tiles_c],
         compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
+        name="fleet_fused_steps",
     )(jnp.asarray(i0, jnp.int32).reshape(1),
       *[to_tiles(l, bd) for l in p], *tiles_c)
     outs = [o.reshape(l.shape) for o, l in zip(outs, c)]
@@ -356,6 +357,7 @@ def serve_fused_steps(
                    for l in out_tmpl],
         compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
+        name="serve_fused_steps",
     )(jnp.asarray(i0, jnp.int32).reshape(1), job0,
       *[to_tiles(l, bd) for l in p], *tiles_c, *lg, *look)
     new_dev = unpack_tree(

@@ -71,6 +71,7 @@ from ..fleet.simulator import finalize_fleet
 from ..kernels._tiling import pairwise_sum
 from ..telemetry import state as T
 from ..telemetry import trace as T_trace
+from ..telemetry.spans import span
 from ..fleet.state import (
     FleetConfig,
     FleetResult,
@@ -204,6 +205,7 @@ def build_feature_tables(
     return dict(sel_feats=sel, full_feats=full, labels=labels)
 
 
+@jax.named_scope("classify")
 def classify_unit(bank: ServeBank, tables: ServeTables, tk, u, job):
     """Single-row live classification for one device's completing unit.
 
@@ -254,6 +256,7 @@ class ServeLookup(NamedTuple):
     thr: jax.Array           # (K*U,) f32 — bank utility thresholds
 
 
+@jax.named_scope("lookup")
 def serve_lookup(tables: ServeTables, centroids) -> ServeLookup:
     """:class:`ServeLookup` of ``tables`` against the bank ``centroids``
     (``([D,] K, U, C, F)``)."""
@@ -267,6 +270,7 @@ def serve_lookup(tables: ServeTables, centroids) -> ServeLookup:
         thr=tables.thr.reshape(-1))
 
 
+@jax.named_scope("classify")
 def _classify_rows(look: ServeLookup, n_tasks: int, tk, u, job):
     """Batch-polymorphic twin of :func:`classify_unit`.
 
@@ -450,9 +454,10 @@ class FleetServeResult:
     (live-mode finalize: correctness from the live registers); the per-job
     arrays are the numpy view of the :class:`ServeLog` (``(D, K, J)``
     each).  ``carry`` is the end-of-horizon :class:`ServeCarry` for
-    checkpoint/resume; ``wall_s``/``jobs_per_sec`` time the jitted scan
-    only (feature precompute excluded — it is amortised, input-dependent
-    work shared with any batched-inference baseline).
+    checkpoint/resume; ``wall_s`` is the host-clock length of the run's
+    ``serve.scan`` span (:func:`repro.telemetry.span`): the jitted scan
+    through the finalize, feature precompute excluded, so ``jobs_per_sec``
+    counts scan time only.
     """
 
     fleet: FleetResult
@@ -592,42 +597,47 @@ class FleetServeEngine:
                  else [cfg.seed] * D)
         if len(seeds) != D:
             raise ValueError(f"{len(seeds)} seeds for {D} devices")
-        events = {s: grid.sample_events(self.harvester, cfg.horizon, s)
-                  for s in set(seeds)}
-        devs = [grid.device_config(
-            tasks, self.harvester, self.eta, self.cap,
-            policy=cfg.policy, horizon=cfg.horizon, events=events[s],
-            e_opt_fraction=cfg.e_opt_fraction,
-            start_charged=cfg.start_charged,
-        ) for s in seeds]
-        fleet_cfg = grid.stack_configs(devs)
+        with span("serve.build.configs"):
+            events = {s: grid.sample_events(self.harvester, cfg.horizon, s)
+                      for s in set(seeds)}
+            devs = [grid.device_config(
+                tasks, self.harvester, self.eta, self.cap,
+                policy=cfg.policy, horizon=cfg.horizon, events=events[s],
+                e_opt_fraction=cfg.e_opt_fraction,
+                start_charged=cfg.start_charged,
+            ) for s in seeds]
+            fleet_cfg = grid.stack_configs(devs)
 
         # one shared stream is featurized once, not once per device
-        feats = [build_feature_tables(
-            self.models, s, self.meta, self._bank_tables,
-            feature_batch=self.feature_batch, n_jobs=max(n_jobs))
-            for s in (streams if per_dev else streams[:1])]
-        if per_dev:
-            stacked = {k: jnp.asarray(np.stack([f[k] for f in feats]))
-                       for k in feats[0]}
-        else:
-            stacked = {k: jnp.asarray(v) for k, v in feats[0].items()}
-        tables = ServeTables(**stacked, **self._bank_tables)
+        featurized = streams if per_dev else streams[:1]
+        with span("serve.build.featurize",
+                  frames=sum(len(r) for s in featurized for r in s)):
+            feats = [build_feature_tables(
+                self.models, s, self.meta, self._bank_tables,
+                feature_batch=self.feature_batch, n_jobs=max(n_jobs))
+                for s in featurized]
+            if per_dev:
+                stacked = {k: jnp.asarray(np.stack([f[k] for f in feats]))
+                           for k in feats[0]}
+            else:
+                stacked = {k: jnp.asarray(v) for k, v in feats[0].items()}
+            tables = ServeTables(**stacked, **self._bank_tables)
 
-        dev0 = jax.vmap(lambda c: init_state(c, statics))(fleet_cfg)
-        bank0 = self.bank0
-        if self.bank_mode == "per-device":
-            bank0 = jax.tree.map(
-                lambda l: jnp.broadcast_to(l, (D,) + l.shape), bank0)
-        K, J = len(self.models), max(n_jobs)
-        log0 = ServeLog(
-            units=jnp.zeros((D, K, J), _I32),
-            pred=jnp.full((D, K, J), -1, _I32),
-            correct=jnp.zeros((D, K, J), bool),
-            margin=jnp.zeros((D, K, J), _F32),
-            exit_unit=jnp.full((D, K, J), -1, _I32),
-            sched=jnp.zeros((D, K, J), bool),
-        )
+        with span("serve.build.carry"):
+            dev0 = jax.vmap(lambda c: init_state(c, statics))(fleet_cfg)
+            bank0 = self.bank0
+            if self.bank_mode == "per-device":
+                bank0 = jax.tree.map(
+                    lambda l: jnp.broadcast_to(l, (D,) + l.shape), bank0)
+            K, J = len(self.models), max(n_jobs)
+            log0 = ServeLog(
+                units=jnp.zeros((D, K, J), _I32),
+                pred=jnp.full((D, K, J), -1, _I32),
+                correct=jnp.zeros((D, K, J), bool),
+                margin=jnp.zeros((D, K, J), _F32),
+                exit_unit=jnp.full((D, K, J), -1, _I32),
+                sched=jnp.zeros((D, K, J), bool),
+            )
         return (fleet_cfg, statics, tables,
                 ServeCarry(dev=dev0, bank=bank0, log=log0), per_dev)
 
@@ -765,6 +775,7 @@ class FleetServeEngine:
                     s.q_deadline[a], c.n_units[tk], c.imprecise,
                     c.use_exit_thr, c.exit_thr[tk, u])
 
+        @jax.named_scope("adapt")
         def adapt_bank(bank, tk, u, job, ci, first_pass):
             Ub = tables.fidx.shape[-2]
             if per_dev_tables:
@@ -902,10 +913,12 @@ class FleetServeEngine:
                 shared: bool, per_dev_tables: bool, tcfg=None):
         key = (statics, n_steps, adapt, shared, per_dev_tables, tcfg)
         if key not in self._runners:
-            self._runners[key] = jax.jit(functools.partial(
+            fn = functools.partial(
                 self._scan_steps, statics=statics, n_steps=n_steps,
                 adapt=adapt, shared=shared, per_dev_tables=per_dev_tables,
-                tcfg=tcfg))
+                tcfg=tcfg)
+            fn.__name__ = "_scan_steps"    # the executable: jit__scan_steps
+            self._runners[key] = jax.jit(fn)
         return self._runners[key]
 
     # ------------------------------------------------------------------ #
@@ -945,95 +958,95 @@ class FleetServeEngine:
         so the fused mode requires ``adapt=False`` — and it has no
         telemetry/mesh hooks.
         """
-        if mode not in ("scan", "fused"):
-            raise ValueError(f"unknown serve mode {mode!r}")
-        adapt = bool(self.config.adapt)
-        if mode == "fused":
-            if adapt:
-                raise ValueError(
-                    "mode='fused' requires adapt=False: bank adaptation "
-                    "propagates centroids through whole-model convs that "
-                    "cannot run inside a device tile")
-            if telemetry is not None or mesh is not None:
-                raise ValueError(
-                    "mode='fused' does not support telemetry= or mesh=")
-        cfg, statics, tables, carry0, per_dev = self.build(
-            requests, n_devices, seeds=seeds)
-        if carry is not None:
-            carry0 = carry
-        shared = self.bank_mode == "shared"
-        tel = (None if telemetry is None
-               else T.init_fleet_telemetry(telemetry, cfg))
-        if mesh is not None:
-            from ..launch.sharding import (
-                shard_fleet_carry,
-                shard_fleet_config,
-                shard_serve_carry,
-                shard_serve_tables,
-            )
-
-            D = cfg.n_devices
-            if D % mesh.size:
-                raise ValueError(
-                    f"D={D} devices must divide over mesh size {mesh.size}")
-            cfg = shard_fleet_config(mesh, cfg)
-            carry0 = shard_serve_carry(mesh, carry0, shared_bank=shared)
-            tables = shard_serve_tables(mesh, tables, per_device=per_dev)
-            if tel is not None:
-                tel = shard_fleet_carry(mesh, tel)
-
-        sizes = [len(c) for c in
-                 np.array_split(np.arange(statics.n_steps), n_segments)]
-        t0 = time.perf_counter()
-        i0 = 0
-        out = carry0
-        for n in sizes:
-            if not n:
-                continue
+        with span("serve.engine.run"):
+            if mode not in ("scan", "fused"):
+                raise ValueError(f"unknown serve mode {mode!r}")
+            adapt = bool(self.config.adapt)
             if mode == "fused":
-                from ..kernels import ops
+                if adapt:
+                    raise ValueError(
+                        "mode='fused' requires adapt=False: bank adaptation "
+                        "propagates centroids through whole-model convs that "
+                        "cannot run inside a device tile")
+                if telemetry is not None or mesh is not None:
+                    raise ValueError(
+                        "mode='fused' does not support telemetry= or mesh=")
+            cfg, statics, tables, carry0, per_dev = self.build(
+                requests, n_devices, seeds=seeds)
+            if carry is not None:
+                carry0 = carry
+            shared = self.bank_mode == "shared"
+            tel = (None if telemetry is None
+                   else T.init_fleet_telemetry(telemetry, cfg))
+            if mesh is not None:
+                from ..launch.sharding import (
+                    shard_fleet_carry,
+                    shard_fleet_config,
+                    shard_serve_carry,
+                    shard_serve_tables,
+                )
 
-                out = ops.serve_fused_steps(
-                    cfg, out, serve_lookup(tables, out.bank.centroids),
-                    jnp.int32(i0),
-                    jnp.zeros((len(self.models),), _I32),
-                    statics=statics, n_steps=n, shared_bank=shared,
-                    per_dev_tables=per_dev)
-                i0 += n
-                continue
-            runner = self._runner(statics, n, adapt, shared, per_dev,
-                                  telemetry)
-            if telemetry is None:
-                out = runner(cfg, tables, out, jnp.int32(i0))
-            else:
-                out, tel, ring = runner(cfg, tables, out, jnp.int32(i0),
-                                        tel)
-                if ring is not None:
-                    spec = T_trace.make_pack_spec(
-                        int(cfg.period.shape[1]), statics.queue_size,
-                        int(tel.exit_hist.shape[1]))
-                    tel = T_trace.fold_events_host(
-                        spec, tel, tuple(np.asarray(c) for c in ring),
-                        i0, statics.dt)
-            i0 += n
-        fleet = finalize_fleet(cfg, out.dev, statics, live=True)
-        jax.block_until_ready(fleet)
-        wall = time.perf_counter() - t0
+                D = cfg.n_devices
+                if D % mesh.size:
+                    raise ValueError(f"D={D} devices must divide over "
+                                     f"mesh size {mesh.size}")
+                cfg = shard_fleet_config(mesh, cfg)
+                carry0 = shard_serve_carry(mesh, carry0, shared_bank=shared)
+                tables = shard_serve_tables(mesh, tables, per_device=per_dev)
+                if tel is not None:
+                    tel = shard_fleet_carry(mesh, tel)
 
-        log = out.log
-        return FleetServeResult(
-            fleet=fleet,
-            units=np.asarray(log.units),
-            pred=np.asarray(log.pred),
-            correct=np.asarray(log.correct),
-            margin=np.asarray(log.margin),
-            exit_unit=np.asarray(log.exit_unit),
-            sched=np.asarray(log.sched),
-            carry=out,
-            jobs=int(np.asarray(fleet.released).sum()),
-            wall_s=wall,
-            telemetry=tel,
-        )
+            sizes = [len(c) for c in
+                     np.array_split(np.arange(statics.n_steps), n_segments)]
+            with span("serve.scan", steps=statics.n_steps) as scan:
+                i0 = 0
+                out = carry0
+                for n in sizes:
+                    if not n:
+                        continue
+                    if mode == "fused":
+                        from ..kernels import ops
+
+                        out = ops.serve_fused_steps(
+                            cfg, out, serve_lookup(tables, out.bank.centroids),
+                            jnp.int32(i0),
+                            jnp.zeros((len(self.models),), _I32),
+                            statics=statics, n_steps=n, shared_bank=shared,
+                            per_dev_tables=per_dev)
+                        i0 += n
+                        continue
+                    runner = self._runner(statics, n, adapt, shared, per_dev,
+                                          telemetry)
+                    if telemetry is None:
+                        out = runner(cfg, tables, out, jnp.int32(i0))
+                    else:
+                        out, tel, ring = runner(cfg, tables, out,
+                                                jnp.int32(i0), tel)
+                        if ring is not None:
+                            spec = T_trace.make_pack_spec(
+                                int(cfg.period.shape[1]), statics.queue_size,
+                                int(tel.exit_hist.shape[1]))
+                            tel = T_trace.fold_events_host(
+                                spec, tel, tuple(np.asarray(c) for c in ring),
+                                i0, statics.dt)
+                    i0 += n
+                fleet = finalize_fleet(cfg, out.dev, statics, live=True)
+                jax.block_until_ready(fleet)
+            with span("serve.fetch"):
+                log = out.log
+                return FleetServeResult(
+                    fleet=fleet,
+                    units=np.asarray(log.units),
+                    pred=np.asarray(log.pred),
+                    correct=np.asarray(log.correct),
+                    margin=np.asarray(log.margin),
+                    exit_unit=np.asarray(log.exit_unit),
+                    sched=np.asarray(log.sched),
+                    carry=out,
+                    jobs=int(np.asarray(fleet.released).sum()),
+                    wall_s=scan.seconds,
+                    telemetry=tel,
+                )
 
     # ------------------------------------------------------------------ #
     # Streaming entry point: O(chunk) device memory for any job total.

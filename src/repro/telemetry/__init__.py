@@ -16,6 +16,9 @@ The measurement substrate every perf PR measures itself against:
   :class:`repro.adapt.online.OnlineAdapter`.
 * :class:`TelemetryLogger` / :func:`read_jsonl` — structured JSONL event
   streams, rendered by ``python -m repro.telemetry.report``.
+* :func:`span` — host spans (``repro:<name>`` ``TraceAnnotation``s with
+  counters) at the layer boundaries of the fleet build and live serving,
+  on the profiler's clock; each keeps its own host-clock ``.seconds``.
 
 Usage::
 
@@ -31,6 +34,7 @@ from .export import (  # noqa: F401
     read_jsonl,
     summarize,
 )
+from .spans import span  # noqa: F401
 from .state import (  # noqa: F401
     EVENT_KINDS,
     EVENT_NAMES,

@@ -91,7 +91,10 @@ def test_fleet_fused_steps_compiles(one_chip):
                                           n_steps=statics.n_steps),
         _on(one_chip, cfg, D), _on(one_chip, carry, D),
         jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip))
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # the kernel's own name, which a device trace finds it by
+    assert "%fleet_fused_steps" in text
 
 
 @pytest.mark.parametrize("per_device_bank", [True, False])
@@ -122,7 +125,9 @@ def test_serve_fused_steps_compiles(one_chip, per_device_bank):
         _on(one_chip, cfg, D), carry, _on(one_chip, look),
         jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
         jax.ShapeDtypeStruct((K,), jnp.int32, sharding=one_chip))
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert "%serve_fused_steps" in text
 
 
 @pytest.mark.parametrize("rows", [4096, 1000])
