@@ -23,6 +23,7 @@ from typing import Optional, Sequence, Union
 
 import numpy as np
 
+import jax
 import jax.numpy as jnp
 
 from ..core import policy as P
@@ -289,28 +290,54 @@ class SweepGrid:
     def tasks(self) -> tuple[TaskSpec, ...]:
         return as_task_set(self.task)
 
+    def axes(self) -> tuple[tuple, ...]:
+        """The grid's axes in device order: policy, eta, harvester,
+        capacitor, seed, clock drift (the harvester and capacitor axes
+        default to one persistent source and one default capacitor)."""
+        return (tuple(self.policies), tuple(self.etas),
+                tuple(self.harvesters or (PERSISTENT,)),
+                tuple(self.capacitors or (Capacitor(),)),
+                tuple(self.seeds), tuple(self.clock_drifts))
+
     def points(self):
-        harvesters = self.harvesters or (PERSISTENT,)
-        capacitors = self.capacitors or (Capacitor(),)
-        for pol in self.policies:
-            for eta in self.etas:
-                for hi, h in enumerate(harvesters):
+        policies, etas, harvesters, capacitors, seeds, drifts = self.axes()
+        for pol in policies:
+            for eta in etas:
+                for h in harvesters:
                     for cap in capacitors:
-                        for seed in self.seeds:
-                            for drift in self.clock_drifts:
+                        for seed in seeds:
+                            for drift in drifts:
                                 yield dict(policy=pol, eta=eta, harvester=h,
-                                           harvester_idx=hi, capacitor=cap,
-                                           seed=seed, clock_drift=drift)
+                                           capacitor=cap, seed=seed,
+                                           clock_drift=drift)
+
+
+@jax.jit
+def _expand(rows: FleetConfig, config_idx, draw_idx) -> FleetConfig:
+    """Gather the distinct rows out to the device axis: ``events`` by
+    ``draw_idx``, every other field by ``config_idx``."""
+    return FleetConfig(**{
+        f: getattr(rows, f)[draw_idx if f == "events" else config_idx]
+        for f in FleetConfig._fields})
 
 
 def build(grid: SweepGrid) -> tuple[FleetConfig, FleetStatics, list[dict]]:
-    """Materialise the grid as a FleetConfig + per-device metadata rows."""
+    """Materialise the grid as a FleetConfig + per-device metadata rows.
+
+    A device's configuration depends on its seed only through its harvest
+    draw, so ``device_config`` runs once per distinct (policy, eta,
+    harvester, capacitor, clock drift) point and ``sample_events`` once per
+    (harvester, seed); the few rows go to the device and are gathered out
+    to the ``D`` devices there, in :meth:`SweepGrid.points` order."""
     with span("fleet.build"):
-        points = list(grid.points())
-        if not points:
+        axes = grid.axes()
+        shape = tuple(len(a) for a in axes)
+        n_dev = int(np.prod(shape))
+        if not n_dev:
             raise ValueError("empty sweep grid")
+        policies, etas, harvesters, capacitors, seeds, drifts = axes
         tasks = grid.tasks
-        slot_lens = {pt["harvester"].slot_s for pt in points}
+        slot_lens = {h.slot_s for h in harvesters}
         if len(slot_lens) != 1:
             raise ValueError("all harvesters in one sweep must share slot_s")
         dt = _check_dt(
@@ -318,30 +345,44 @@ def build(grid: SweepGrid) -> tuple[FleetConfig, FleetStatics, list[dict]]:
         statics = FleetStatics(queue_size=grid.queue_size, dt=dt,
                                horizon=grid.horizon, slot_s=slot_lens.pop())
 
-        events_cache: dict[tuple[int, int], np.ndarray] = {}
-        devices, meta = [], []
-        with span("fleet.build.configs"):
-            for pt in points:
-                key = (pt["harvester_idx"], pt["seed"])
-                if key not in events_cache:
-                    events_cache[key] = sample_events(
-                        pt["harvester"], grid.horizon, pt["seed"])
-                devices.append(device_config(
-                    tasks, pt["harvester"], pt["eta"], pt["capacitor"],
-                    policy=pt["policy"], horizon=grid.horizon,
-                    events=events_cache[key],
-                    e_opt_fraction=grid.e_opt_fraction, e_man=grid.e_man,
-                    start_charged=grid.start_charged,
-                    clock_drift=pt["clock_drift"],
-                ))
-                meta.append(dict(
-                    policy=pt["policy"], eta=pt["eta"],
-                    harvester=pt["harvester"].name, seed=pt["seed"],
-                    capacitance_f=pt["capacitor"].capacitance_f,
-                    clock_drift=pt["clock_drift"],
-                    n_tasks=len(tasks),
-                ))
-        return stack_configs(devices), statics, meta
+        # the seed axis (4) is the only one a configuration row ignores
+        config_shape = shape[:4] + shape[5:]
+        with span("fleet.build.configs", devices=n_dev,
+                  configs=int(np.prod(config_shape))):
+            draws, draw_of = [], {}
+            draw_table = np.empty((len(harvesters), len(seeds)), np.int32)
+            for hi, h in enumerate(harvesters):
+                for si, seed in enumerate(seeds):
+                    if (hi, seed) not in draw_of:
+                        draw_of[hi, seed] = len(draws)
+                        draws.append(sample_events(h, grid.horizon, seed))
+                    draw_table[hi, si] = draw_of[hi, seed]
+            rows = [device_config(
+                tasks, harvesters[hi], etas[ei], capacitors[ci],
+                policy=policies[pi], horizon=grid.horizon,
+                events=draws[draw_table[hi, 0]],
+                e_opt_fraction=grid.e_opt_fraction, e_man=grid.e_man,
+                start_charged=grid.start_charged, clock_drift=drifts[ri])
+                for pi, ei, hi, ci, ri in np.ndindex(config_shape)]
+            # each device's position on every axis, in points() order
+            pos = np.indices(shape).reshape(len(shape), -1)
+            config_idx = np.ravel_multi_index(
+                np.delete(pos, 4, axis=0), config_shape).astype(np.int32)
+            draw_idx = draw_table[pos[2], pos[4]]
+        with span("fleet.build.stack"):
+            stacked = {f: np.stack([d[f] for d in rows])
+                       for f in FleetConfig._fields if f != "events"}
+            cfg = _expand(FleetConfig(events=np.stack(draws), **stacked),
+                          config_idx, draw_idx)
+
+        meta = [dict(
+            policy=pt["policy"], eta=pt["eta"],
+            harvester=pt["harvester"].name, seed=pt["seed"],
+            capacitance_f=pt["capacitor"].capacitance_f,
+            clock_drift=pt["clock_drift"],
+            n_tasks=len(tasks),
+        ) for pt in grid.points()]
+        return cfg, statics, meta
 
 
 def sweep(grid: SweepGrid, use_pallas=None, mesh=None, mode=None):

@@ -20,7 +20,7 @@ from repro.serve import fleet_engine
 from repro.telemetry import span
 from repro.telemetry.spans import PREFIX
 
-from _workloads import make_task
+from _workloads import make_task, random_task_set
 
 N_JOBS, N_DEV = 3, 2
 
@@ -118,12 +118,15 @@ def test_profiler_trace_holds_nested_spans_with_counters(engine, requests,
     assert _inside(by["serve.fetch"][0], run)
 
     # the counters, recorded where the work happens: one shared stream is
-    # featurized once
+    # featurized once; the build makes one configuration per (policy, eta)
+    # point of the grid's 2 x 2 x 2 seeds
     n_steps = engine.build(requests, n_devices=N_DEV)[1].n_steps
     assert by["serve.scan"][0][3] == {"steps": n_steps}
     assert by["serve.build.featurize"][0][3] == {"frames": N_JOBS}
+    assert by["fleet.build.configs"][0][3] == {"devices": 8, "configs": 4}
     assert all(not s[3] for n, v in by.items() for s in v
-               if n not in ("serve.scan", "serve.build.featurize"))
+               if n not in ("serve.scan", "serve.build.featurize",
+                            "fleet.build.configs"))
     assert res.jobs == N_DEV * N_JOBS
 
 
@@ -155,10 +158,36 @@ def test_serve_scan_hlo_names_its_stages(engine, requests):
             "apply"} <= scopes
 
 
-def test_fleet_build_returns_the_same_arrays():
+_SOLAR = energy.Harvester("solar", 0.95, 0.95, 0.08)
+_RF = energy.Harvester("rf", 0.9, 0.8, 0.07)
+_CAPS = (energy.Capacitor(), energy.Capacitor(0.02, 3.3, 1.8))
+
+# grids in which each axis a configuration depends on has more than one
+# value, against the edge cases of the seed axis
+BUILD_GRIDS = {
+    "every_axis": dict(
+        task=make_task(n_jobs=6), policies=("zygarde", "edf"),
+        etas=(0.5, 1.0), harvesters=(_RF, _SOLAR), capacitors=_CAPS,
+        seeds=(3, 4, 5), clock_drifts=(0.0, 1e-3), horizon=8.0),
+    "task_set_charged_e_man": dict(
+        task=random_task_set(22, 2), policies=("zygarde", "edf-m"),
+        etas=(0.2, 0.8), harvesters=(_SOLAR, _RF), capacitors=_CAPS,
+        seeds=(7, 8, 9), clock_drifts=(0.0, 2e-3), horizon=8.0,
+        start_charged=True, e_man=2e-3),
+    "one_seed": dict(
+        task=make_task(n_jobs=6), policies=("rr", "edf"), etas=(0.5, 1.0),
+        harvesters=(_RF, _SOLAR), seeds=(11,), horizon=8.0),
+    "repeated_seed": dict(
+        task=make_task(n_jobs=6), policies=("zygarde",), etas=(0.5, 1.0),
+        harvesters=(_RF,), seeds=(5, 5, 6), horizon=8.0),
+}
+
+
+@pytest.mark.parametrize("name", list(BUILD_GRIDS))
+def test_fleet_build_returns_the_same_arrays(name):
     """``fleet.build`` against the device-by-device construction it
-    stacks: the spans around its stages change no array."""
-    grid = _grid(seeds=(3, 4, 5))
+    replaces: one ``device_config`` a grid point, stacked."""
+    grid = fleet.SweepGrid(**BUILD_GRIDS[name])
     cfg, statics, meta = fleet.build(grid)
     points = list(grid.points())
     assert len(meta) == len(points) == cfg.n_devices
@@ -175,3 +204,27 @@ def test_fleet_build_returns_the_same_arrays():
         got = np.asarray(getattr(cfg, f))
         assert got.dtype == want.dtype and np.array_equal(got, want), f
     assert [m["seed"] for m in meta] == [pt["seed"] for pt in points]
+
+
+def test_fleet_build_makes_one_config_per_distinct_point(monkeypatch):
+    """Seeds share their point's configuration: ``device_config`` runs
+    once per (policy, eta, harvester, capacitor, drift), and ``meta`` keeps
+    one row per device in grid order."""
+    from repro.fleet import grid as fgrid
+
+    grid = fleet.SweepGrid(**BUILD_GRIDS["every_axis"])
+    real, calls = fgrid.device_config, []
+
+    def counting(*args, **kw):
+        calls.append(kw["policy"])
+        return real(*args, **kw)
+
+    monkeypatch.setattr(fgrid, "device_config", counting)
+    cfg, _, meta = fleet.build(grid)
+    assert len(calls) == 2 * 2 * 2 * 2 * 2
+    assert cfg.n_devices == len(calls) * 3
+    assert meta == [dict(policy=pt["policy"], eta=pt["eta"],
+                         harvester=pt["harvester"].name, seed=pt["seed"],
+                         capacitance_f=pt["capacitor"].capacitance_f,
+                         clock_drift=pt["clock_drift"], n_tasks=1)
+                    for pt in grid.points()]
