@@ -270,6 +270,35 @@ def serve_lookup(tables: ServeTables, centroids) -> ServeLookup:
         thr=tables.thr.reshape(-1))
 
 
+@jax.named_scope("lookup")
+def refresh_cent_rows(cent_rows, centroids, fidx, tk, ci, moved):
+    """``cent_rows`` (``([D,] K*U*C, S)``, :attr:`ServeLookup.cent_rows`)
+    brought up to the adapted bank ``centroids`` (``([D,] K, U, C, F)``).
+
+    A per-device adaptation moves rows ``(tk_d, :, ci_d)`` of each device
+    ``d`` with ``moved`` set, and no others: the assigned row and the rows
+    its propagation refreshes.  So only those U rows a device are selected
+    again — a row gather, then ``fidx[tk_d]``'s columns of it — and every
+    other row keeps its bits.  A shared bank (no device axis) takes
+    updates from many devices at once; its small view is selected whole.
+    Either way the result is ``select_centroids(centroids, fidx)`` bit for
+    bit, since a gather copies values."""
+    if centroids.ndim == fidx.ndim + 1:
+        return select_centroids(centroids, fidx).reshape(cent_rows.shape)
+    K, U, C, _ = centroids.shape[1:]
+    Sn = fidx.shape[-1]
+
+    def one(rows, cents, t, c, m):
+        new = jnp.take_along_axis(cents[t, :, c], fidx[t], axis=-1)  # (U, S)
+        hit = (m & (jnp.arange(K)[:, None, None, None] == t)
+               & (jnp.arange(C)[None, None, :, None] == c))
+        rows = jnp.where(hit, new[None, :, None, :],
+                         rows.reshape((K, U, C, Sn)))
+        return rows.reshape((K * U * C, Sn))
+
+    return jax.vmap(one)(cent_rows, centroids, tk, ci, moved)
+
+
 @jax.named_scope("classify")
 def _classify_rows(look: ServeLookup, n_tasks: int, tk, u, job):
     """Batch-polymorphic twin of :func:`classify_unit`.
@@ -776,7 +805,10 @@ class FleetServeEngine:
                     c.use_exit_thr, c.exit_thr[tk, u])
 
         @jax.named_scope("adapt")
-        def adapt_bank(bank, tk, u, job, ci, first_pass):
+        def adapt_bank(bank, rows, tk, u, job, ci, first_pass):
+            """The adapted bank, and its selected-column view ``rows``
+            (``None`` where the caller classifies from the bank itself)
+            refreshed to match it."""
             Ub = tables.fidx.shape[-2]
             if per_dev_tables:
                 x_full = tables.full_feats[
@@ -787,39 +819,46 @@ class FleetServeEngine:
                 x_full = S.take_rows(ff, (tk * J + job) * Ub + u)
 
             def _upd(args):
-                b, xf, tkk, uu, cii, fp = args
+                b, r, xf, tkk, uu, cii, fp = args
                 if shared:
-                    return self._adapt_shared(b, xf, tkk, uu, cii, fp)
-                return jax.vmap(self._adapt_per_device)(
-                    b, xf, tkk, uu, cii, fp)
+                    b = self._adapt_shared(b, xf, tkk, uu, cii, fp)
+                else:
+                    b = jax.vmap(self._adapt_per_device)(
+                        b, xf, tkk, uu, cii, fp)
+                if r is not None:
+                    r = refresh_cent_rows(r, b.centroids, tables.fidx, tkk,
+                                          cii, fp)
+                return b, r
 
-            # most steps complete nothing: skip the propagation convs
-            # entirely unless some device's utility test just passed
+            # each device passes rarely, but across the fleet some device
+            # passes on most steps; a step where none does skips the
+            # propagation convs and the refresh
             return lax.cond(
-                jnp.any(first_pass), _upd, lambda args: args[0],
-                (bank, x_full, tk, u, ci, first_pass))
+                jnp.any(first_pass), _upd, lambda args: args[:2],
+                (bank, rows, x_full, tk, u, ci, first_pass))
 
         look0 = serve_lookup(tables, carry.bank.centroids)
+        # an adapting bank carries its selected columns through the scan,
+        # refreshed where adaptation moves them; a frozen one's stay look0's
+        rows0 = look0.cent_rows if adapt and not trace else None
 
         def step(carry, i):
-            dev, bank, log = carry
+            (dev, bank, log), rows = carry
             t = i.astype(_F32) * statics.dt
-            # an adapting bank moves every step; a frozen one is selected
-            # once, outside the scan
-            look = (serve_lookup(tables, bank.centroids) if adapt
-                    else look0)
+            look = look0 if rows is None else look0._replace(cent_rows=rows)
             dev, flog, (first_pass, tk, u, job, ci) = serve_step(
                 cfg, look, dev, flat_log(log), t, job0, statics=statics)
             log = ServeLog(*[f.reshape(l.shape) for f, l in zip(flog, log)])
             if adapt:
-                bank = adapt_bank(bank, tk, u, job, ci, first_pass)
-            new_carry = ServeCarry(dev=dev, bank=bank, log=log)
+                bank, rows = adapt_bank(bank, rows, tk, u, job, ci,
+                                        first_pass)
+            new_carry = (ServeCarry(dev=dev, bank=bank, log=log), rows)
             if counters:
                 return new_carry, T_trace.emit_counters(dev)
             return new_carry, None
 
         def step_trace(carry, i):
-            dev, bank, log = carry
+            (dev, bank, log), _ = carry
             dev0 = dev
             t = i.astype(_F32) * statics.dt
             act0 = dev.q_active
@@ -867,7 +906,7 @@ class FleetServeEngine:
                 q_apass=dev.q_apass | (oh & (complete & pass_bank)[:, None]))
 
             if adapt:
-                bank = adapt_bank(bank, tk, u, job, ci, first_pass)
+                bank, _ = adapt_bank(bank, None, tk, u, job, ci, first_pass)
 
             # per-job outcome log (mirrors apply_step's completion math)
             exit_now = complete & imprec & (exited_pre < 0) & passed
@@ -891,17 +930,17 @@ class FleetServeEngine:
                 exit_unit=put(log.exit_unit, u, first_pass),
                 sched=put(log.sched, sched_now, mand_now),
             )
-            new_carry = ServeCarry(dev=dev, bank=bank, log=log)
+            new_carry = (ServeCarry(dev=dev, bank=bank, log=log), None)
             return new_carry, T_trace.emit_full(spec, tr, dev0, dev)
 
         if trace:
             step = step_trace
 
-        if tcfg is None:
-            carry, _ = lax.scan(step, carry, i0 + jnp.arange(n_steps))
-            return carry
         st0 = carry.dev
-        carry, ys = lax.scan(step, carry, i0 + jnp.arange(n_steps))
+        (carry, _), ys = lax.scan(step, (carry, rows0),
+                                  i0 + jnp.arange(n_steps))
+        if tcfg is None:
+            return carry
         if counters:
             return carry, T_trace.reduce_counters(tel, st0, carry.dev, ys,
                                                   n_steps), None
@@ -1034,13 +1073,19 @@ class FleetServeEngine:
                 jax.block_until_ready(fleet)
             with span("serve.fetch"):
                 log = out.log
+                exit_unit = np.asarray(log.exit_unit)
+                if adapt:
+                    # each first pass refreshed <= U view rows of its device
+                    with span("serve.bank.refresh",
+                              refreshes=int((exit_unit >= 0).sum())):
+                        pass
                 return FleetServeResult(
                     fleet=fleet,
                     units=np.asarray(log.units),
                     pred=np.asarray(log.pred),
                     correct=np.asarray(log.correct),
                     margin=np.asarray(log.margin),
-                    exit_unit=np.asarray(log.exit_unit),
+                    exit_unit=exit_unit,
                     sched=np.asarray(log.sched),
                     carry=out,
                     jobs=int(np.asarray(fleet.released).sum()),

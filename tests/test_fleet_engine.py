@@ -191,3 +191,65 @@ def test_shared_bank_collaborative_adaptation(trained_cnn, mnist_tiny):
     # the single shared bank gained mass (counts only ever grow)
     assert (np.asarray(fres.carry.bank.counts).sum()
             > float(np.asarray(eng.bank0.counts).sum()))
+
+
+@pytest.mark.parametrize("passing", ["none", "one", "same_cluster"])
+@pytest.mark.parametrize("bank_mode", ["per-device", "shared"])
+def test_refreshed_view_equals_a_fresh_select(trained_cnn, bank_mode,
+                                              passing):
+    """The adapting scan carries the bank's selected columns and refreshes
+    only the rows an adaptation moved: after one adaptation of a random
+    bank, the refreshed view is ``select_centroids`` of the updated bank,
+    bit for bit — with no device passing, one, and several on the same
+    (task, unit) and cluster."""
+    from repro.serve.fleet_engine import refresh_cent_rows, select_centroids
+
+    D = 4
+    eng = FleetServeEngine([_fresh_model(trained_cnn) for _ in range(2)],
+                           _persistent(), eta=1.0,
+                           config=_cfg("zygarde", 2, True),
+                           bank_mode=bank_mode)
+    K, U, C, F = eng.bank0.centroids.shape
+    Sn = 7
+    shared = bank_mode == "shared"
+    lead = () if shared else (D,)
+    rng = np.random.default_rng(["none", "one", "same_cluster"].index(
+        passing) + 10 * shared)
+    bank = eng.bank0._replace(
+        centroids=jnp.asarray(rng.normal(size=lead + (K, U, C, F)),
+                              jnp.float32),
+        counts=jnp.asarray(rng.integers(1, 40, lead + (K, U, C)),
+                           jnp.float32))
+    # each classifier's columns lie inside its unit's feature width
+    fidx = jnp.asarray([[rng.integers(0, fu, Sn) for fu in dims]
+                        for dims in eng.meta.feat_dim], jnp.int32)
+    x_full = jnp.asarray(rng.normal(size=(D, F)), jnp.float32)
+    tk = rng.integers(0, K, D)
+    u = rng.integers(0, U, D)
+    ci = rng.integers(0, C, D)
+    first_pass = np.zeros(D, bool)
+    if passing == "one":
+        # a pass at the first unit propagates to every deeper unit's row
+        first_pass[1], u[1] = True, 0
+    elif passing == "same_cluster":
+        first_pass[:3] = True
+        tk[:3], u[:3], ci[:3] = tk[0], u[0], ci[0]
+    args = [jnp.asarray(a, jnp.int32) for a in (tk, u, ci)]
+    fp = jnp.asarray(first_pass)
+
+    @jax.jit
+    def adapt_and_refresh(bank):
+        view = select_centroids(bank.centroids, fidx)
+        view = view.reshape(view.shape[:-4] + (-1, Sn))
+        if shared:
+            new = eng._adapt_shared(bank, x_full, *args, fp)
+        else:
+            new = jax.vmap(eng._adapt_per_device)(bank, x_full, *args, fp)
+        fresh = select_centroids(new.centroids, fidx).reshape(view.shape)
+        return view, refresh_cent_rows(view, new.centroids, fidx, args[0],
+                                       args[2], fp), fresh
+
+    view, refreshed, fresh = map(np.asarray, adapt_and_refresh(bank))
+    assert np.array_equal(refreshed, fresh)
+    # the adaptation moved the view exactly where some device passed
+    assert np.array_equal(view, fresh) == (passing == "none")

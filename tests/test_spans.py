@@ -102,7 +102,8 @@ def test_profiler_trace_holds_nested_spans_with_counters(engine, requests,
         "fleet.build": 1, "fleet.build.configs": 1, "fleet.simulate": 1,
         "fleet.build.stack": 2, "serve.engine.run": 1,
         "serve.build.configs": 1, "serve.build.featurize": 1,
-        "serve.build.carry": 1, "serve.scan": 1, "serve.fetch": 1}
+        "serve.build.carry": 1, "serve.scan": 1, "serve.fetch": 1,
+        "serve.bank.refresh": 1}
 
     (build,), (run,) = by["fleet.build"], by["serve.engine.run"]
     assert _inside(by["fleet.build.configs"][0], build)
@@ -116,6 +117,7 @@ def test_profiler_trace_holds_nested_spans_with_counters(engine, requests,
         assert _inside(by[a][0], run)
         assert by[a][0][2] <= by[b][0][1]
     assert _inside(by["serve.fetch"][0], run)
+    assert _inside(by["serve.bank.refresh"][0], run)
 
     # the counters, recorded where the work happens: one shared stream is
     # featurized once; the build makes one configuration per (policy, eta)
@@ -124,9 +126,12 @@ def test_profiler_trace_holds_nested_spans_with_counters(engine, requests,
     assert by["serve.scan"][0][3] == {"steps": n_steps}
     assert by["serve.build.featurize"][0][3] == {"frames": N_JOBS}
     assert by["fleet.build.configs"][0][3] == {"devices": 8, "configs": 4}
+    # one bank refresh a first pass, each the first pass of one job
+    assert by["serve.bank.refresh"][0][3] == {
+        "refreshes": int((res.exit_unit >= 0).sum())}
     assert all(not s[3] for n, v in by.items() for s in v
                if n not in ("serve.scan", "serve.build.featurize",
-                            "fleet.build.configs"))
+                            "fleet.build.configs", "serve.bank.refresh"))
     assert res.jobs == N_DEV * N_JOBS
 
 
@@ -156,6 +161,51 @@ def test_serve_scan_hlo_names_its_stages(engine, requests):
               for part in name.split("/")}
     assert {"lookup", "classify", "adapt", "admit", "expire", "pick",
             "apply"} <= scopes
+
+
+def _reachable(hlo: str, root: str) -> set:
+    """Names of the HLO computations ``root`` runs, itself included."""
+    comps, name = {}, None
+    for line in hlo.splitlines():
+        if line and not line.startswith(" ") and line.endswith("{"):
+            name = line.split()[1 if line.startswith("ENTRY") else 0]
+            comps[name] = []
+        elif name is not None:
+            comps[name].append(line)
+    seen, todo = set(), [root]
+    while todo:
+        c = todo.pop()
+        if c in seen:
+            continue
+        seen.add(c)
+        for one, many in re.findall(
+                r"(?:to_apply|calls|body|condition)=([\w.\-]+)"
+                r"|branch_computations=\{([^}]*)\}", "\n".join(comps[c])):
+            todo += [one] if one else [b.strip() for b in many.split(",")]
+    return seen
+
+
+def test_adapting_scan_selects_the_whole_bank_once(engine, requests):
+    """The adapting scan gathers the bank's selected columns over the whole
+    bank once, before its loop: the loop body gathers only the rows an
+    adaptation moved."""
+    cfg, statics, tables, carry0, per_dev = engine.build(requests,
+                                                         n_devices=N_DEV)
+    runner = engine._runner(statics, statics.n_steps, True, False, per_dev)
+    hlo = runner.lower(cfg, tables, carry0, jnp.int32(0)).as_text(
+        dialect="hlo")
+    view = carry0.bank.centroids.shape[:-1] + tables.fidx.shape[-1:]
+    whole = re.compile(r"= f32\[%s\]\{[^}]*\} gather\("
+                       % ",".join(map(str, view)))
+    (body,) = re.findall(r" while\(.*body=([\w.\-]+)", hlo)
+    in_loop = _reachable(hlo, body)
+    where, name = [], None
+    for line in hlo.splitlines():
+        if line and not line.startswith(" ") and line.endswith("{"):
+            name = line.split()[1 if line.startswith("ENTRY") else 0]
+        elif whole.search(line):
+            where.append(name)
+    assert len(where) == 1 and where[0] not in in_loop
 
 
 _SOLAR = energy.Harvester("solar", 0.95, 0.95, 0.08)
